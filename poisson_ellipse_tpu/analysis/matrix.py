@@ -83,8 +83,8 @@ def enumerate_cells(
 def load_suppressions(root: Optional[str] = None) -> dict[str, str]:
     """``[tool.engine_contracts] suppress`` entries -> {cell id: reason}.
 
-    Reuses the tpulint pyproject reader (tomllib with the flat-array
-    subset fallback), so the knob parses identically everywhere.
+    Reuses the tpulint pyproject reader, so the knob parses identically
+    everywhere.
     """
     import os
 

@@ -654,7 +654,7 @@ def _run_tune(argv: list[str]) -> int:
         "engine configurations from measured telemetry (obs.spectrum "
         "Ritz-predicted iterations, obs.profile GB/s), pick a winner "
         "that provably does not lose to the static default, and "
-        "optionally persist it next to the XLA compile cache for "
+        "optionally persist it in the checkout (.autotune/) for "
         "engine='auto' and the serve warm pool to consult.",
     )
     ap.add_argument("--grid", help="MxN grid to tune (default 40x40)")
@@ -679,11 +679,12 @@ def _run_tune(argv: list[str]) -> int:
     ap.add_argument(
         "--persist", action="store_true",
         help="write the winner into the tuned-config registry "
-        "(autotune.json next to the XLA compile cache)",
+        "(<repo>/.autotune/registry.json)",
     )
     ap.add_argument(
         "--registry", metavar="FILE", default=None,
-        help="registry path override (default: next to the XLA cache)",
+        help="registry path override "
+        "(default: <repo>/.autotune/registry.json)",
     )
     ap.add_argument("--trace", metavar="FILE", help="JSONL trace sink")
     ap.add_argument("--json", action="store_true", help="one JSON line")
@@ -797,19 +798,21 @@ def _run_tune(argv: list[str]) -> int:
 def _run_warmup(argv: list[str]) -> int:
     """The ``warmup`` subcommand: pre-fill the compilation caches.
 
-    Wires up the persistent XLA cache and AOT-compiles the batched
-    engines' bucket executables for the requested grids/lane counts
-    (``runtime.compile_cache``), so a serving worker's first real
-    request is a cache hit instead of a cold compile. Hit/miss counts
+    AOT-compiles the batched engines' bucket executables for the
+    requested grids/lane counts (``runtime.compile_cache``) — into the
+    persistent cache ``main`` turned on — so a serving worker's first
+    real request is a cache hit instead of a cold compile. Hit/miss counts
     land on the trace (``cache:hit`` / ``cache:miss`` events).
     """
+    import jax
+
     from poisson_ellipse_tpu.runtime import compile_cache
 
     ap = argparse.ArgumentParser(
         prog="python -m poisson_ellipse_tpu.harness warmup",
-        description="Warm the compilation caches: enable the persistent "
-        "XLA cache and AOT-compile bucketed executables for the batched "
-        "engines, keyed (engine, grid-bucket, dtype, lane-bucket). "
+        description="Warm the compilation caches: AOT-compile bucketed "
+        "executables for the batched engines, keyed (engine, "
+        "grid-bucket, dtype, lane-bucket). "
         "Arbitrary request sizes then hit a warm executable by "
         "pad-and-mask embedding.",
     )
@@ -826,15 +829,6 @@ def _run_warmup(argv: list[str]) -> int:
         choices=("batched", "batched-pipelined", "both"),
     )
     ap.add_argument("--dtype", choices=sorted(DTYPES), default="f32")
-    ap.add_argument(
-        "--cache-dir", default=None,
-        help="persistent XLA cache directory (default: "
-        "$POISSON_COMPILE_CACHE or ~/.cache/poisson_ellipse_tpu/xla)",
-    )
-    ap.add_argument(
-        "--no-persistent", action="store_true",
-        help="skip the persistent XLA cache wiring (in-process pool only)",
-    )
     ap.add_argument("--trace", metavar="FILE", help="JSONL trace sink")
     ap.add_argument("--json", action="store_true", help="one JSON line")
     args = ap.parse_args(argv)
@@ -842,10 +836,11 @@ def _run_warmup(argv: list[str]) -> int:
     if args.trace:
         obs_trace.start(args.trace)
     try:
-        if not args.no_persistent:
-            cache_dir = compile_cache.enable_persistent_cache(args.cache_dir)
-        else:
-            cache_dir = None
+        # main() already turned the persistent cache on; report where
+        cache_dir = (
+            jax.config.jax_compilation_cache_dir
+            if jax.config.jax_enable_compilation_cache else None
+        )
         engines = (
             ("batched", "batched-pipelined")
             if args.engine == "both"
@@ -1505,6 +1500,10 @@ def _run_fleet(argv: list[str]) -> int:
 
 def main(argv=None) -> int:
     argv = sys.argv[1:] if argv is None else list(argv)
+    from poisson_ellipse_tpu.runtime import compile_cache
+
+    # every subcommand compiles: the cache goes on before the first one
+    compile_cache.enable_persistent_cache()
     if argv and argv[0] == "inspect":
         return _run_inspect(argv[1:])
     if argv and argv[0] == "inject":
@@ -1650,8 +1649,8 @@ def main(argv=None) -> int:
         type=int,
         default=1,
         help="TIMING protocol: dispatches chained per repetition so the "
-        "fixed host<->device RTT cancels out of T_solver. This does NOT "
-        "batch solves onto the chip — that is --lanes",
+        "fixed per-dispatch host overhead cancels out of T_solver. This "
+        "does NOT batch solves onto the chip — that is --lanes",
     )
     ap.add_argument(
         "--lanes",
@@ -1728,7 +1727,7 @@ def main(argv=None) -> int:
     ap.add_argument(
         "--profile",
         action="store_true",
-        help="segmented per-phase iteration profile (stage4 timer taxonomy)",
+        help="segmented per-phase iteration profile (stage4 timers)",
     )
     ap.add_argument(
         "--trace-dir",
@@ -2021,7 +2020,7 @@ def _run_cli(args) -> int:
                         ),
                         dtype=jdtype,
                     )
-                # the stage4 taxonomy as spans: halo/stencil/dot/... per
+                # the stage4 timers as spans: halo/stencil/dot/... per
                 # iteration, from the segmented replay
                 for name, secs in sorted(phases.items()):
                     obs_trace.span_event(f"profile:{name}", secs)
@@ -2047,7 +2046,7 @@ def _run_cli(args) -> int:
                     if jax.default_backend() != "cpu":
                         print(
                             "note: single-dispatch T_solver includes the "
-                            "fixed host<->device round-trip; pass e.g. "
+                            "fixed per-dispatch host overhead; pass e.g. "
                             "--repeat 3 --batch 5 for the amortised "
                             "protocol bench.py uses",
                             file=sys.stderr,

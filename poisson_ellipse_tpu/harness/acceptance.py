@@ -17,6 +17,9 @@ the iteration count, so the reference oracle cannot apply to them; their
 rows gate on the ROADMAP's pivot instead — converged, strictly fewer
 iterations than the diagonal oracle, and l2-vs-analytic no more than
 10% above the diagonal solve's (one-sided: more accurate never fails).
+The s-step rows hit the oracle only with the f64 Gram x64 provides; in
+an x64-off process (the chip's default) they gate on the measured band
+of their f32-Gram counts (``F32_GRAM_CEILINGS``).
 
 ``--headline`` adds the 400×600 row (546 iterations) with the auto
 engine. Exit code 0 iff every row passes.
@@ -31,9 +34,11 @@ import jax
 import jax.numpy as jnp
 
 from poisson_ellipse_tpu.models.problem import Problem
+from poisson_ellipse_tpu.ops.sstep_pcg import gram_dtype
 from poisson_ellipse_tpu.solver.engine import (
     ENGINES,
     PRECOND_ENGINES,
+    SSTEP_ENGINES,
     build_solver,
 )
 
@@ -41,6 +46,15 @@ from poisson_ellipse_tpu.solver.engine import (
 # compiled and run; see BASELINE.md "Iteration counts")
 SMALL_ORACLES = {(10, 10): 15, (20, 20): 26, (40, 40): 50}
 HEADLINE = ((400, 600), 546)
+# x64 off (the chip's default) the s-step Gram accumulates in f32
+# (ops.sstep_pcg.gram_dtype) and the block the s=4 recurrence stops in
+# becomes a rounding fact: under 40 one-ulp RHS perturbations (CPU, PR
+# 21) the counts spread over 15 / 26-33 / 50-67 at 10²/20²/40², the
+# same for both stencils; the chip gave sstep 51, sstep-pallas 63 at
+# 40². The s-step rows' band is [oracle, ceiling], each ceiling that
+# spread's top + 1 — the one-bf16-pass contraction defect the chip
+# showed (70 at 40²) still fails it.
+F32_GRAM_CEILINGS = {(10, 10): 16, (20, 20): 34, (40, 40): 68}
 
 
 def _diag_l2(M: int, N: int, _cache={}) -> float:
@@ -107,10 +121,14 @@ def _row(engine: str, M: int, N: int, oracle: int) -> tuple[bool, str]:
                 f"l2={l2:.2e} (diag {ref:.2e})"
             )
             return ok, note
-        ok = converged and abs(iters - oracle) <= slack
-        note = f"iters={iters} (oracle {oracle}" + (
-            f"±{slack})" if slack else ")"
-        )
+        lo, hi = oracle - slack, oracle + slack
+        band = f"±{slack}" if slack else ""
+        if (engine in SSTEP_ENGINES
+                and jnp.dtype(gram_dtype(jnp.float32)) == jnp.float32):
+            lo, hi = oracle, F32_GRAM_CEILINGS[(M, N)]
+            band = f"..{hi}, f32 Gram"
+        ok = converged and lo <= iters <= hi
+        note = f"iters={iters} (oracle {oracle}{band})"
         if lanes > 1:
             note += f" [{lanes} lanes]"
         if resolved != engine:
@@ -121,29 +139,38 @@ def _row(engine: str, M: int, N: int, oracle: int) -> tuple[bool, str]:
 
 
 def _sharded_row(
-    M: int, N: int, oracle: int, stencil_impl: str = "xla"
+    M: int, N: int, oracle: int, stencil_impl: str, devices
 ) -> tuple[bool, str]:
+    from poisson_ellipse_tpu.parallel.mesh import make_mesh
     from poisson_ellipse_tpu.parallel.pcg_sharded import solve_sharded
 
     slack = 2 if stencil_impl == "pipelined" else 0
     try:
         result = solve_sharded(
-            Problem(M=M, N=N), dtype=jnp.float32, stencil_impl=stencil_impl
+            Problem(M=M, N=N), mesh=make_mesh(devices), dtype=jnp.float32,
+            stencil_impl=stencil_impl,
         )
         iters = int(result.iters)
         ok = bool(result.converged) and abs(iters - oracle) <= slack
         note = (
             f"iters={iters} (oracle {oracle}"
             + (f"±{slack})" if slack else ")")
-            + f" over {len(jax.devices())} device(s)"
+            + f" over {len(devices)} device(s)"
         )
     except Exception as e:  # tpulint: disable=TPU009 — the failure becomes the report row
         ok, note = False, f"{type(e).__name__}: {e}"
     return ok, note
 
 
-def run_acceptance(headline: bool = False, out=sys.stderr) -> bool:
-    print(f"backend: {jax.default_backend()}  devices: {jax.devices()}",
+def run_acceptance(headline: bool = False, out=sys.stderr,
+                   grids=tuple(SMALL_ORACLES), devices=None) -> bool:
+    """Print one row per (grid, engine) and return whether all passed.
+
+    ``grids`` picks which of ``SMALL_ORACLES``' grids the engine rows
+    run at; the sharded rows run at the last of them, over ``devices``
+    (default: all)."""
+    devices = jax.devices() if devices is None else list(devices)
+    print(f"backend: {jax.default_backend()}  devices: {devices}",
           file=out)
     all_ok = True
     # fmg is gated elsewhere, not by the oracle matrix: its iteration
@@ -153,21 +180,23 @@ def run_acceptance(headline: bool = False, out=sys.stderr) -> bool:
     # check drives it through the real CLI, and the bench `fmg` key
     # gates it on the chip
     engines = [e for e in ENGINES if e not in ("auto", "fmg")]
-    for (M, N), oracle in SMALL_ORACLES.items():
+    for M, N in grids:
+        oracle = SMALL_ORACLES[(M, N)]
         for engine in engines:
             ok, note = _row(engine, M, N, oracle)
             all_ok &= ok
             print(f"  {'ok ' if ok else 'FAIL'} {M}x{N} {engine:9s} {note}",
                   file=out)
-    for (M, N), oracle in list(SMALL_ORACLES.items())[-1:]:
-        for impl in ("xla", "pallas", "fused", "pipelined"):
-            ok, note = _sharded_row(M, N, oracle, stencil_impl=impl)
-            all_ok &= ok
-            print(
-                f"  {'ok ' if ok else 'FAIL'} {M}x{N} "
-                f"{'sharded/' + impl:14s} {note}",
-                file=out,
-            )
+    M, N = grids[-1]
+    for impl in ("xla", "pallas", "fused", "pipelined"):
+        ok, note = _sharded_row(M, N, SMALL_ORACLES[(M, N)], impl,
+                                devices)
+        all_ok &= ok
+        print(
+            f"  {'ok ' if ok else 'FAIL'} {M}x{N} "
+            f"{'sharded/' + impl:14s} {note}",
+            file=out,
+        )
     if headline:
         (M, N), oracle = HEADLINE
         ok, note = _row("auto", M, N, oracle)
@@ -187,6 +216,11 @@ def main(argv=None) -> int:
         help="also run 400x600 (546-iteration oracle) with the auto engine",
     )
     args = ap.parse_args(argv)
+    from poisson_ellipse_tpu.runtime.compile_cache import (
+        enable_persistent_cache,
+    )
+
+    enable_persistent_cache()
     return 0 if run_acceptance(headline=args.headline) else 1
 
 

@@ -65,8 +65,8 @@ def passes_per_iter(problem: Problem, engine: str, dtype=jnp.float32,
         zr dot (fused into z)                                 0
         p = z + beta*p (read z?, p; write p — z fused)        1
         => ~13 passes (matches the measured HBM-bound regime)
-      fused — K1 reads z, p, 5 coefficient arrays, writes pn, ap (9);
-        K2 reads w, r, pn, ap, dinv, writes w, r, z (8) => 17
+      fused — K1 reads z, p, 4 coefficient arrays, writes pn, ap (8);
+        K2 reads w, r, pn, ap, dinv, writes w, r, z (8) => 16
         (more traffic than xla — why it only wins while compute-bound)
       pipelined / pipelined-pallas — bundle+stencil pass reads
         r, u, w, s, p, dinv, a, b and writes n (9); the seven-vector
@@ -96,7 +96,7 @@ def passes_per_iter(problem: Problem, engine: str, dtype=jnp.float32,
 
         return 13.0 + modeled_extra_passes(problem, engine, dtype)
     if engine == "fused":
-        return 17.0
+        return 16.0
     if engine in ("pipelined", "pipelined-pallas"):
         from poisson_ellipse_tpu.ops.precision import replace_every
 

@@ -30,7 +30,7 @@ import dataclasses
 import fnmatch
 import json
 import os
-import re
+import tomllib
 from typing import Iterable, Optional
 
 from poisson_ellipse_tpu.lint.report import Finding, ParseError
@@ -60,55 +60,9 @@ __all__ = [
 # -- configuration ----------------------------------------------------------
 
 
-def _parse_toml_subset(text: str) -> dict:
-    """Minimal TOML reader for the ``[tool.tpulint]`` table.
-
-    This interpreter ships neither ``tomllib`` (3.11+) nor ``tomli``, and
-    the repo vendors nothing, so the loader falls back to a subset
-    parser: ``[section]`` headers, ``key = value`` with string / integer /
-    flat string-array values, ``#`` comments. Exactly the shapes the
-    tpulint table uses; anything fancier should go through ``tomllib``.
-    """
-    data: dict = {}
-    section = data
-    for raw in text.splitlines():
-        line = raw.strip()
-        if not line or line.startswith("#"):
-            continue
-        if line.startswith("[") and line.endswith("]"):
-            section = data
-            for part in line[1:-1].strip().strip('"').split("."):
-                section = section.setdefault(part.strip().strip('"'), {})
-            continue
-        if "=" not in line:
-            continue
-        key, _, value = line.partition("=")
-        key = key.strip().strip('"')
-        value = value.strip()
-        if value.startswith("[") and value.endswith("]"):
-            items = re.findall(r'"((?:[^"\\]|\\.)*)"', value)
-            section[key] = list(items)
-        elif value.startswith('"') and value.endswith('"'):
-            section[key] = value[1:-1]
-        elif value in ("true", "false"):
-            section[key] = value == "true"
-        else:
-            try:
-                section[key] = int(value)
-            except ValueError:
-                section[key] = value
-    return data
-
-
 def _read_pyproject(path: str) -> dict:
-    with open(path, encoding="utf-8") as f:
-        text = f.read()
-    try:
-        import tomllib  # Python 3.11+
-
-        return tomllib.loads(text)
-    except ImportError:
-        return _parse_toml_subset(text)
+    with open(path, "rb") as f:
+        return tomllib.load(f)
 
 
 def load_config(root: Optional[str] = None) -> LintConfig:

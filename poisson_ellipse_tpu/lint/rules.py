@@ -1877,7 +1877,7 @@ def check_swallowed_exception(module: Module, config: LintConfig) -> Iterator[Fi
     no return codes at all — SURVEY §5; this rule is the regression
     fence for the opposite stance). A broad handler is compliant when
     its body re-raises (anything — the classified ``SolveError``
-    taxonomy in ``resilience.errors`` is the house idiom) or hands the
+    classification in ``resilience.errors`` is the house idiom) or hands the
     exception to a ``reraise-fns``-configured helper; genuinely
     deliberate swallows (best-effort accounting, report-the-failure
     rows) carry a ``# tpulint: disable=TPU009`` with a note, exactly

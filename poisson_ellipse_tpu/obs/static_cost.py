@@ -74,10 +74,6 @@ def xla_cost(fn, args) -> dict | None:
         analysis = compiled.cost_analysis()
     except Exception:  # tpulint: disable=TPU009 — introspection must never break a run
         return None
-    if analysis is None:
-        return None
-    if isinstance(analysis, (list, tuple)):  # older jax: one dict per device
-        analysis = analysis[0] if analysis else None
     if not isinstance(analysis, dict):
         return None
     flops = analysis.get("flops")
@@ -243,7 +239,10 @@ def engine_report(
             counts.get("ppermute", 0) / per_body
             if per_body > 1 else counts.get("ppermute", 0)
         ),
-        "collectives_per_iter": {k: v for k, v in counts.items() if v},
+        "collectives_per_iter": {
+            k: v for k, v in {**counts, "psum": psum}.items()
+            if v and k != "psum_invariant"
+        },
         "flops_per_iter_est": cost["flops"] if cost else None,
         "hbm_bytes_per_iter_est": cost["bytes_accessed"] if cost else None,
         "modeled_passes_per_iter": passes,

@@ -42,7 +42,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from poisson_ellipse_tpu.parallel.compat import shape_dtype_struct
 
 # Rows of output computed per grid step. 128 keeps the three (TM+2)-row
 # f32 input windows + one TM-row output tile a few MB — comfortably in
@@ -173,7 +172,7 @@ def apply_a_block_pallas(w_ext, a_ext, b_ext, h1, h2, interpret=None,
         out_specs=pl.BlockSpec(
             (tm, bn), lambda i: (i, 0), memory_space=pltpu.VMEM
         ),
-        out_shape=shape_dtype_struct((k, bn), dtype, vma=vma),
+        out_shape=jax.ShapeDtypeStruct((k, bn), dtype, vma=vma),
         scratch_shapes=[
             pltpu.VMEM((tm + 8, cols), dtype),
             pltpu.VMEM((tm + 8, cols), dtype),
@@ -306,8 +305,8 @@ def apply_a_block_dots_pallas(w_ext, a_ext, b_ext, h1, h2, pairs,
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ),
         out_shape=(
-            shape_dtype_struct((k, bn), dtype, vma=vma),
-            shape_dtype_struct((n_pairs,), dtype, vma=vma),
+            jax.ShapeDtypeStruct((k, bn), dtype, vma=vma),
+            jax.ShapeDtypeStruct((n_pairs,), dtype, vma=vma),
         ),
         scratch_shapes=[
             pltpu.VMEM((tm + 8, cols), dtype),
@@ -788,7 +787,7 @@ def apply_a_block_mixed_pallas(w_ext, a_ext, b_ext, h1, h2,
         out_specs=pl.BlockSpec(
             (tm, bn), lambda i: (i, 0), memory_space=pltpu.VMEM
         ),
-        out_shape=shape_dtype_struct((k, bn), out_dtype, vma=vma),
+        out_shape=jax.ShapeDtypeStruct((k, bn), out_dtype, vma=vma),
         scratch_shapes=[
             pltpu.VMEM((tm + 8, cols), w_p.dtype),
             pltpu.VMEM((tm + 8, cols), a_p.dtype),
@@ -917,8 +916,8 @@ def apply_a_block_dots_mixed_pallas(w_ext, a_ext, b_ext, h1, h2, pairs,
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ),
         out_shape=(
-            shape_dtype_struct((k, bn), compute, vma=vma),
-            shape_dtype_struct((n_pairs,), compute, vma=vma),
+            jax.ShapeDtypeStruct((k, bn), compute, vma=vma),
+            jax.ShapeDtypeStruct((n_pairs,), compute, vma=vma),
         ),
         scratch_shapes=[
             pltpu.VMEM((tm + 8, cols), w_p.dtype),

@@ -20,9 +20,9 @@ pure VPU arithmetic (measured 3.5 us/iter @ 400x600, 7.9 @ 800x1200,
 structure costs. Grids that don't fit fall back to the streamed
 whole-solve kernel (``ops.streamed_pcg``) — ``solver.engine`` picks.
 
-Arithmetic is the normalised-stencil form shared with ``fused_pcg``
-(coefficients pre-divided by h^2 and pre-masked to the interior; the
-preconditioner a multiply by a precomputed guarded 1/D), with the same
+Arithmetic is the stencil form shared with ``fused_pcg`` (coefficients
+pre-divided by h^2 and pre-masked to the interior, differences first;
+the preconditioner a multiply by a precomputed guarded 1/D), with the same
 rotated loop whose value sequence matches the reference order
 (``stage0/Withoutopenmp1.cpp:124-169``). The z iterate is eliminated
 algebraically (p = r*Dinv + beta*p), which drops one resident array and
@@ -41,7 +41,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from poisson_ellipse_tpu.models.problem import Problem
-from poisson_ellipse_tpu.parallel.compat import tpu_compiler_params
 from poisson_ellipse_tpu.ops import assembly
 from poisson_ellipse_tpu.ops.fused_pcg import fused_operands
 from poisson_ellipse_tpu.solver.pcg import DENOM_GUARD, PCGResult
@@ -52,11 +51,12 @@ from poisson_ellipse_tpu.utils.device import scaled_vmem_budget
 # via ``utils.device.scaled_vmem_budget`` (device_kind-keyed table).
 _VMEM_LIMIT = 127 * 1024 * 1024
 _RESIDENT_BUDGET = 125 * 1024 * 1024
-# Empirical working-set envelope: operands (6 coeffs + rhs) + scratch
+# Empirical working-set envelope: operands (5 coeffs + rhs) + scratch
 # state (w, r, p) + w_out + ~6 Mosaic temporaries during the whole-array
 # stencil/update expressions. Chip-measured with the scratch-state
-# kernel: 1100x1650 (17 arrays = 124.9 MB) compiles and converges;
-# 1200x1800 (157.7 MB) fails Mosaic allocation — hence BUDGET=125 MB.
+# kernel, then still holding a 6th coefficient (D): 1100x1650 (17
+# arrays = 124.9 MB) compiles and converges; 1200x1800 (157.7 MB) fails
+# Mosaic allocation — hence BUDGET=125 MB.
 _ARRAYS_RESIDENT = 17
 
 
@@ -103,7 +103,7 @@ def _shift_cols_left(x):
 
 
 def _mega_kernel(h1, h2, delta, weighted, max_iter,
-                 an, as_, bw, be, d, dinv, r0,
+                 an, as_, bw, be, dinv, r0,
                  w_out, iters_out, diff_out, flags_out,
                  w_s, r_s, p_s):
     """The full PCG solve. Runs as a single grid-less invocation.
@@ -122,7 +122,6 @@ def _mega_kernel(h1, h2, delta, weighted, max_iter,
     as_v = as_[...]
     bw_v = bw[...]
     be_v = be[...]
-    d_v = d[...]
     dinv_v = dinv[...]
     r_init = r0[...]
 
@@ -151,11 +150,11 @@ def _mega_kernel(h1, h2, delta, weighted, max_iter,
         k, zr, beta, diff, _cv, _bd = c
         pn = r_s[...] * dinv_v + beta * p_s[...]
         p_s[...] = pn
-        ap = d_v * pn - (
-            an_v * _shift_rows_down(pn)
-            + as_v * _shift_rows_up(pn)
-            + bw_v * _shift_cols_right(pn)
-            + be_v * _shift_cols_left(pn)
+        ap = (
+            an_v * (pn - _shift_rows_down(pn))
+            + as_v * (pn - _shift_rows_up(pn))
+            + bw_v * (pn - _shift_cols_right(pn))
+            + be_v * (pn - _shift_cols_left(pn))
         )
         denom = jnp.sum(ap * pn) * h1h2
         breakdown = denom < DENOM_GUARD
@@ -233,7 +232,7 @@ def build_resident_solver(problem: Problem, dtype=jnp.float32,
     smem = lambda: pl.BlockSpec(memory_space=pltpu.SMEM)
     call = pl.pallas_call(
         kernel,
-        in_specs=[vmem()] * 7,
+        in_specs=[vmem()] * 6,
         out_specs=(vmem(), smem(), smem(), smem()),
         out_shape=(
             jax.ShapeDtypeStruct((g1p, g2p), dtype),
@@ -246,7 +245,7 @@ def build_resident_solver(problem: Problem, dtype=jnp.float32,
             pltpu.VMEM((g1p, g2p), dtype),  # r
             pltpu.VMEM((g1p, g2p), dtype),  # p
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=scaled_vmem_budget(_VMEM_LIMIT)
         ),
         interpret=interpret,

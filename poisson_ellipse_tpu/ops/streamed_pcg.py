@@ -68,7 +68,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from poisson_ellipse_tpu.models.problem import Problem
-from poisson_ellipse_tpu.parallel.compat import tpu_compiler_params
 from poisson_ellipse_tpu.ops import assembly
 from poisson_ellipse_tpu.solver.pcg import DENOM_GUARD, PCGResult
 from poisson_ellipse_tpu.utils.device import scaled_vmem_budget
@@ -654,7 +653,7 @@ def build_streamed_solver(problem: Problem, dtype=jnp.float32,
             buf("ap"),
             pltpu.SemaphoreType.DMA((8,)),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=scaled_vmem_budget(_VMEM_LIMIT)
         ),
         interpret=interpret,

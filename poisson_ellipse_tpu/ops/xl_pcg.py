@@ -58,7 +58,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from poisson_ellipse_tpu.models.problem import Problem
-from poisson_ellipse_tpu.parallel.compat import tpu_compiler_params
 from poisson_ellipse_tpu.ops.streamed_pcg import (
     _VMEM_LIMIT,
     _interpret_default,
@@ -478,7 +477,7 @@ def build_xl_solver(problem: Problem, dtype=jnp.float32, interpret=None,
             pltpu.SMEM((3,), dtype),
             pltpu.SemaphoreType.DMA((_NSEMS,)),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=scaled_vmem_budget(_VMEM_LIMIT)
         ),
         interpret=interpret,

@@ -21,9 +21,6 @@ exchange (``:241-347``, ``poisson_mpi_cuda2.cu:331-500``) and
               solve — ONE stacked ``psum`` per iteration (all dot
               partials together), overlapped by XLA with the halo
               exchange + stencil; the collective-latency engine,
-- ``compat``: the jax-version shim every sharding call site routes
-              through (``shard_map`` location/checker kwarg, ``pcast``,
-              vma-annotated ShapeDtypeStructs, Mosaic compiler params),
 - ``multihost``: ``jax.distributed.initialize`` lifecycle (= MPI_Init/
               Finalize) and the all-hosts global mesh — the same solver
               code rides ICI within a slice and DCN across hosts.

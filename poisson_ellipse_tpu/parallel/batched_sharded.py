@@ -33,7 +33,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from poisson_ellipse_tpu.batch import batched_pcg, batched_pipelined
@@ -44,8 +44,12 @@ from poisson_ellipse_tpu.batch.batched_pcg import (
     diag_d_batched,
 )
 from poisson_ellipse_tpu.models.problem import Problem
-from poisson_ellipse_tpu.parallel.compat import pcast_varying, shard_map
-from poisson_ellipse_tpu.parallel.mesh import AXIS_X, AXIS_Y, make_mesh
+from poisson_ellipse_tpu.parallel.mesh import (
+    AXIS_X,
+    AXIS_Y,
+    make_mesh,
+    pcast_varying,
+)
 
 MESH_AXES = (AXIS_X, AXIS_Y)
 

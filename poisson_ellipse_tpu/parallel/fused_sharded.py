@@ -49,7 +49,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -64,13 +64,13 @@ from poisson_ellipse_tpu.ops.fused_pcg import (
     rotated_state0,
 )
 from poisson_ellipse_tpu.ops.pallas_kernels import _row_tile, round_up
-from poisson_ellipse_tpu.parallel.compat import (
-    pcast_varying,
-    shape_dtype_struct,
-    shard_map,
-)
 from poisson_ellipse_tpu.parallel.halo import halo_extend, halo_extend_stacked
-from poisson_ellipse_tpu.parallel.mesh import AXIS_X, AXIS_Y, make_mesh
+from poisson_ellipse_tpu.parallel.mesh import (
+    AXIS_X,
+    AXIS_Y,
+    make_mesh,
+    pcast_varying,
+)
 from poisson_ellipse_tpu.solver.pcg import DENOM_GUARD, PCGResult
 
 MESH_AXES = (AXIS_X, AXIS_Y)
@@ -182,9 +182,9 @@ def build_shard_kernels(bm: int, bn: int, h1: float, h2: float, dtype,
         in_specs=[smem(), blk1(), any_(), any_(), any_(), any_()],
         out_specs=(blk1(), blk1(), smem()),
         out_shape=(
-            shape_dtype_struct((bm, bn), dtype, vma=vma),
-            shape_dtype_struct((bm, bn), dtype, vma=vma),
-            shape_dtype_struct((1,), dtype, vma=vma),
+            jax.ShapeDtypeStruct((bm, bn), dtype, vma=vma),
+            jax.ShapeDtypeStruct((bm, bn), dtype, vma=vma),
+            jax.ShapeDtypeStruct((1,), dtype, vma=vma),
         ),
         scratch_shapes=[
             pltpu.VMEM((tm1 + 8, cols), dtype),
@@ -210,10 +210,10 @@ def build_shard_kernels(bm: int, bn: int, h1: float, h2: float, dtype,
         in_specs=[smem(), smem(), blk2(), blk2(), blk2(), blk2(), blk2()],
         out_specs=(blk2(), blk2(), blk2(), smem()),
         out_shape=(
-            shape_dtype_struct((bm, bn), dtype, vma=vma),
-            shape_dtype_struct((bm, bn), dtype, vma=vma),
-            shape_dtype_struct((bm, bn), dtype, vma=vma),
-            shape_dtype_struct((2,), dtype, vma=vma),
+            jax.ShapeDtypeStruct((bm, bn), dtype, vma=vma),
+            jax.ShapeDtypeStruct((bm, bn), dtype, vma=vma),
+            jax.ShapeDtypeStruct((bm, bn), dtype, vma=vma),
+            jax.ShapeDtypeStruct((2,), dtype, vma=vma),
         ),
         scratch_shapes=[pltpu.SMEM((2,), dtype)],
         interpret=interpret,

@@ -18,6 +18,7 @@ import os
 
 import jax
 import numpy as np
+from jax import lax
 from jax.sharding import Mesh
 
 AXIS_X = "x"
@@ -74,6 +75,16 @@ def virtual_cpu_devices(n: int):
         )
     jax.config.update("jax_platforms", "cpu")
     return jax.devices("cpu")
+
+
+def pcast_varying(x, axis_names):
+    """Mark ``x`` varying over each of ``axis_names`` it is not already
+    varying over — a literal built inside ``shard_map`` is invariant, and
+    a while_loop carry must match the per-device updates' type.
+    ``lax.pcast`` refuses varying→varying, so only the missing axes are
+    cast (``jax.typeof(x).vma`` is what ``x`` already varies over)."""
+    missing = tuple(a for a in axis_names if a not in jax.typeof(x).vma)
+    return lax.pcast(x, missing, to="varying") if missing else x
 
 
 def choose_process_grid(size: int) -> tuple[int, int]:

@@ -29,7 +29,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from poisson_ellipse_tpu.mg import cheby, coarsen as mg_coarsen, vcycle
@@ -41,7 +41,6 @@ from poisson_ellipse_tpu.ops.stencil import (
     apply_dinv,
     diag_d_block,
 )
-from poisson_ellipse_tpu.parallel.compat import shard_map
 from poisson_ellipse_tpu.parallel.halo import halo_extend
 from poisson_ellipse_tpu.parallel.mesh import AXIS_X, AXIS_Y, make_mesh
 from poisson_ellipse_tpu.parallel.pcg_sharded import (

@@ -21,7 +21,6 @@ from typing import Optional
 
 import jax
 
-from poisson_ellipse_tpu.parallel.compat import distributed_is_initialized
 from poisson_ellipse_tpu.parallel.mesh import make_mesh
 
 
@@ -39,7 +38,7 @@ def initialize_multihost(
     backend. Idempotence guard: a second call is a no-op rather than an
     error, matching how the reference tolerates only one MPI_Init.
     """
-    if distributed_is_initialized():
+    if jax.distributed.is_initialized():
         return
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
@@ -51,7 +50,7 @@ def initialize_multihost(
 
 def shutdown_multihost() -> None:
     """``MPI_Finalize`` analog."""
-    if distributed_is_initialized():
+    if jax.distributed.is_initialized():
         jax.distributed.shutdown()
 
 

@@ -27,7 +27,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from poisson_ellipse_tpu.models.problem import Problem
@@ -38,9 +38,14 @@ from poisson_ellipse_tpu.obs.convergence import (
 )
 from poisson_ellipse_tpu.ops import assembly
 from poisson_ellipse_tpu.ops.stencil import apply_a_block, apply_dinv, diag_d_block
-from poisson_ellipse_tpu.parallel.compat import pcast_varying, shard_map
 from poisson_ellipse_tpu.parallel.halo import halo_extend
-from poisson_ellipse_tpu.parallel.mesh import AXIS_X, AXIS_Y, make_mesh, padded_dims
+from poisson_ellipse_tpu.parallel.mesh import (
+    AXIS_X,
+    AXIS_Y,
+    make_mesh,
+    padded_dims,
+    pcast_varying,
+)
 from poisson_ellipse_tpu.solver.pcg import DENOM_GUARD, PCGResult
 
 
@@ -84,7 +89,7 @@ def _shard_ops(problem: Problem, px: int, py: int, bm: int, bn: int,
                 apply_a_block_pallas(
                     p_ext, a_ext, b_ext, problem.h1, problem.h2,
                     interpret=interpret,
-                    vma=(AXIS_X, AXIS_Y),
+                    vma=frozenset((AXIS_X, AXIS_Y)),
                 )
                 * maskd
             )

@@ -53,16 +53,21 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from poisson_ellipse_tpu.models.problem import Problem
 from poisson_ellipse_tpu.ops import assembly
 from poisson_ellipse_tpu.ops.pipelined_pcg import REPLACE_EVERY, _bundle
 from poisson_ellipse_tpu.ops.stencil import apply_a_block, apply_dinv, diag_d_block
-from poisson_ellipse_tpu.parallel.compat import pcast_varying, shard_map
 from poisson_ellipse_tpu.parallel.halo import halo_extend, halo_extend_stacked
-from poisson_ellipse_tpu.parallel.mesh import AXIS_X, AXIS_Y, make_mesh, padded_dims
+from poisson_ellipse_tpu.parallel.mesh import (
+    AXIS_X,
+    AXIS_Y,
+    make_mesh,
+    padded_dims,
+    pcast_varying,
+)
 from poisson_ellipse_tpu.parallel.pcg_sharded import _host_sharded_args
 from poisson_ellipse_tpu.solver.pcg import DENOM_GUARD, PCGResult
 

@@ -41,7 +41,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from poisson_ellipse_tpu.models.problem import Problem
@@ -62,7 +62,6 @@ from poisson_ellipse_tpu.ops.sstep_pcg import (
     sstep_inner,
 )
 from poisson_ellipse_tpu.ops.stencil import apply_a_block, apply_dinv, diag_d_block
-from poisson_ellipse_tpu.parallel.compat import shard_map
 from poisson_ellipse_tpu.parallel.halo import halo_extend, halo_extend_stacked
 from poisson_ellipse_tpu.parallel.mesh import AXIS_X, AXIS_Y, make_mesh, padded_dims
 from poisson_ellipse_tpu.parallel.pcg_sharded import (
@@ -311,7 +310,9 @@ def make_sstep_parts(problem, mesh, dtype, s, storage_dtype=None,
                 )
             return out
 
-        return lax.while_loop(cond, body, state)
+        # full-f32 contractions on a TPU (see ops.sstep_pcg.advance)
+        with jax.default_matmul_precision("highest"):
+            return lax.while_loop(cond, body, state)
 
     init_mapped = jax.jit(shard_map(  # tpulint: disable=TPU004
         init_shard,

@@ -10,7 +10,7 @@ service survived it":
   (direction-preserving, oracle-parity), f32→f64 precision escalation,
   engine fallback — capped by ``max_recoveries`` and classified on
   exhaustion.
-- :mod:`.errors` — the :class:`SolveError` taxonomy and the harness
+- :mod:`.errors` — the :class:`SolveError` classification and the harness
   exit-code contract (2 = diverged, 3 = oom, 4 = timeout), plus the one
   place device-runtime OOM strings are sniffed.
 - :mod:`.faultinject` — deterministic fault injection (NaN into a named

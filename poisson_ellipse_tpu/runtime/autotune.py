@@ -7,7 +7,7 @@ bandwidth (``obs.profile``), and the per-engine traffic models
 (``harness.roofline`` / ``mg.engine.modeled_extra_passes``). This
 module closes the loop: score every candidate engine configuration for
 a shape from that telemetry, pick a winner that provably does not lose
-to the static default, persist it next to the XLA compile cache, and
+to the static default, persist it at a fixed path in the checkout, and
 let ``solver.engine.build_solver(engine="auto")`` and the serve
 scheduler's batch contexts (``Scheduler._ctx_for``, the per-bucket
 tuned chunk) consult the persisted registry at admission.
@@ -149,17 +149,16 @@ def tune_key(problem: Problem, dtype=jnp.float32, storage_dtype=None,
 # -- persistence -------------------------------------------------------------
 
 
-def registry_path(cache_dir: str | None = None) -> str:
-    """``autotune.json`` next to the persistent XLA compile cache
-    directory (``runtime.compile_cache``): the same lifecycle — wiped
-    together, shipped together, warmed together."""
-    from poisson_ellipse_tpu.runtime import compile_cache
+def registry_path() -> str:
+    """``<repo>/.autotune/registry.json``: a fixed path in the checkout.
 
-    base = cache_dir or os.environ.get(
-        compile_cache.ENV_CACHE_DIR
-    ) or compile_cache.DEFAULT_CACHE_DIR
-    return os.path.join(os.path.dirname(base.rstrip(os.sep)),
-                        "autotune.json")
+    It deliberately does NOT follow ``$JAX_COMPILATION_CACHE_DIR``: the
+    registry steers which engine ``engine="auto"`` resolves to, so a
+    compile cache shared between checkouts must never carry one
+    checkout's tuning into another."""
+    from poisson_ellipse_tpu.runtime.compile_cache import REPO_ROOT
+
+    return os.path.join(REPO_ROOT, ".autotune", "registry.json")
 
 
 class TuneRegistry:

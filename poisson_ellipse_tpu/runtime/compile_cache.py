@@ -6,10 +6,13 @@ recompiles everything a fresh worker ever traces) and once per *shape*
 worker). Two layers here, one per failure mode:
 
 - **Persistent XLA compilation cache** (:func:`enable_persistent_cache`)
-  — ``jax_compilation_cache_dir`` wiring with the min-compile-time gate
-  dropped to zero, so every compiled solver (any engine) lands on disk
-  and a restarted worker deserialises instead of recompiling. Ambient
-  activation via ``POISSON_COMPILE_CACHE=DIR``.
+  — JAX's on-disk cache with the min-compile-time gate dropped to zero,
+  so every compiled solver (any engine) lands on disk and a restarted
+  worker deserialises instead of recompiling. Every entry point (the
+  harness CLI, ``bench.py``, ``chip_smoke.py``) turns it on at start.
+  It lives in ``$JAX_COMPILATION_CACHE_DIR`` when that is set, else at
+  the fixed ``<repo>/.jax_cache/`` — the path is part of the cache key,
+  so it never moves.
 
 - **In-process AOT warm pool** (:class:`WarmPool`) — bucketed
   ahead-of-time executables for the *batched* engines, keyed by
@@ -54,38 +57,32 @@ from poisson_ellipse_tpu.models.problem import Problem
 from poisson_ellipse_tpu.obs import metrics as obs_metrics
 from poisson_ellipse_tpu.obs import trace as obs_trace
 
-ENV_CACHE_DIR = "POISSON_COMPILE_CACHE"
-DEFAULT_CACHE_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "poisson_ellipse_tpu", "xla"
+# the checkout this package runs from: everything the program writes
+# (compile cache, autotune registry, native library) stays inside it
+REPO_ROOT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_CACHE_DIR = os.path.join(REPO_ROOT, ".jax_cache")
 
-_persistent_dir: str | None = None
 
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
 
-def enable_persistent_cache(path: str | None = None) -> str:
-    """Point XLA's persistent compilation cache at ``path`` (default:
-    ``$POISSON_COMPILE_CACHE`` or ``~/.cache/poisson_ellipse_tpu/xla``).
-
-    Drops the min-compile-time gate to zero so even millisecond compiles
-    persist — the solver zoo is many small computations, and a restarted
-    serving worker wants all of them back. Idempotent; returns the
-    directory in use.
+    ``$JAX_COMPILATION_CACHE_DIR`` wins when set — JAX reads it itself,
+    so no other directory is set here; otherwise ``<repo>/.jax_cache/``.
+    Drops the min-compile-time and min-size gates so even millisecond
+    compiles persist — the solver zoo is many small computations. Call
+    it before the first compile: JAX decides once per process whether
+    the cache is in use.
     """
-    global _persistent_dir
-    path = path or os.environ.get(ENV_CACHE_DIR) or DEFAULT_CACHE_DIR
-    if _persistent_dir == path:
-        return path
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    try:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    except AttributeError:  # older jax spells it differently / lacks it
-        pass
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except AttributeError:
-        pass
-    _persistent_dir = path
+    path = os.environ.get(ENV_CACHE_DIR)
+    if not path:
+        path = DEFAULT_CACHE_DIR
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     obs_trace.event("cache:persistent-enabled", dir=path)
     return path
 

@@ -3,7 +3,9 @@
 The reference ships one Makefile for its CUDA stage only
 (``stage4-mpi+cuda/Makefile``) and builds stage0/1 ad hoc; here the
 native library is built on first use with g++ (-O3 -fopenmp, falling
-back to no-OpenMP if unavailable) and cached next to the source. No
+back to no-OpenMP if unavailable) and cached next to the source under a
+name carrying the source's hash, so a library built from any other
+version of the source (a stale, untracked copy) is never loaded. No
 pybind11 in this environment — the C ABI + ctypes keeps the binding
 dependency-free.
 """
@@ -11,6 +13,8 @@ dependency-free.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -22,7 +26,6 @@ from poisson_ellipse_tpu.models.problem import Problem
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "pe_runtime.cpp")
-_LIB = os.path.join(_DIR, "libpe_runtime.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -42,14 +45,22 @@ class NativeResult(NamedTuple):
     breakdown: bool
 
 
-def _build() -> Optional[str]:
+def _lib_path() -> str:
+    """The library built from ``pe_runtime.cpp`` as it is now."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"libpe_runtime-{digest}.so")
+
+
+def _build(lib_path: str) -> Optional[str]:
     """Compile the shared library; returns an error string on failure.
 
     Compiles to a process-unique temp name and os.rename()s onto the
     final path: rename is atomic, so a concurrent process never dlopens
     a half-written library (the in-module lock is process-local only).
+    Libraries built from older sources are removed.
     """
-    tmp = f"{_LIB}.{os.getpid()}.tmp"
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     # attempt order: drop -march=native (not every g++/arch accepts it)
     # and -fopenmp independently so losing one flag never costs the other
     attempts = (
@@ -77,7 +88,10 @@ def _build() -> Optional[str]:
         except (OSError, subprocess.TimeoutExpired) as e:
             return f"g++ invocation failed: {e}"
         if proc.returncode == 0:
-            os.replace(tmp, _LIB)
+            os.replace(tmp, lib_path)
+            for stale in glob.glob(os.path.join(_DIR, "libpe_runtime*.so")):
+                if stale != lib_path:
+                    os.unlink(stale)
             return None
         err = proc.stderr
     return f"g++ failed:\n{err}"
@@ -88,13 +102,12 @@ def _load() -> Optional[ctypes.CDLL]:
     with _lock:
         if _lib is not None or _build_error is not None:
             return _lib
-        if not os.path.exists(_LIB) or os.path.getmtime(
-            _LIB
-        ) < os.path.getmtime(_SRC):
-            _build_error = _build()
+        lib_path = _lib_path()
+        if not os.path.exists(lib_path):
+            _build_error = _build(lib_path)
             if _build_error is not None:
                 return None
-        lib = ctypes.CDLL(_LIB)
+        lib = ctypes.CDLL(lib_path)
         d = ctypes.c_double
         dp = np.ctypeslib.ndpointer(np.float64, flags="C_CONTIGUOUS")
         lib.pe_solve.restype = ctypes.c_int

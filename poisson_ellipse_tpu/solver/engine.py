@@ -365,12 +365,13 @@ def build_solver(
     automatically when "auto" consults a persisted config, so the
     configuration that was scored is the configuration that runs.
 
-    "auto" degrades gracefully: the capacity gates are budgets measured
-    on the bench part, so on a chip with a different VMEM size a selected
-    Pallas engine could fail Mosaic compilation — auto AOT-compiles the
-    pick and falls down the chain (resident → streamed → xl → xla; xla
-    cannot fail this way) instead of surfacing an opaque compile error.
-    Explicitly requested engines still fail loudly.
+    "auto" degrades only on memory: the capacity gates are budgets
+    measured on the bench part, so on a TPU auto AOT-compiles the pick
+    and, when the compiler or allocator reports memory exhaustion
+    (``resilience.errors.is_oom_error``), falls down the chain (resident
+    → streamed → xl → xla) with a ``RuntimeWarning``. Any other failure
+    — a kernel Mosaic refuses — is raised: it is a bug, not a capacity
+    fact. Explicitly requested engines always fail loudly.
     """
     if lanes != 1 and engine not in BATCHED_ENGINES:
         raise ValueError(
@@ -431,7 +432,7 @@ def build_solver(
     if engine == "auto":
         # the autotuner's persisted, regression-gated winner for this
         # shape (runtime.autotune) overrides the static capacity ladder
-        # — only when a tuned registry exists next to the XLA cache and
+        # — only when the checkout's tuned registry exists and
         # holds this key; otherwise the historical ladder is untouched
         from poisson_ellipse_tpu.runtime import autotune
 
@@ -463,9 +464,10 @@ def build_solver(
     if engine == "auto":
         import jax
 
+        from poisson_ellipse_tpu.resilience.errors import is_oom_error
+
         chain = CAPACITY_LADDER
         chain = chain[chain.index(select_engine(problem, dtype)):]
-        last_err = None
         for cand in chain:
             try:
                 # the gate already ran above — don't re-validate per rung
@@ -476,26 +478,23 @@ def build_solver(
                 if cand != "xla" and jax.default_backend() == "tpu":
                     # force Mosaic compilation now, where we can catch it.
                     # The jit dispatch cache is shared with this AOT
-                    # lowering (verified on the bench chip: first solver
-                    # call after this line dispatches in ~1 ms, no
-                    # recompile), so the probe costs nothing extra.
+                    # lowering, so the probe costs nothing extra.
                     solver.lower(*args).compile()
                 return solver, args, cand
-            except Exception as e:  # tpulint: disable=TPU009 — chain: warn, degrade, re-raise at exhaustion
-                last_err = e
-                if cand != chain[-1]:
-                    import warnings
+            except Exception as e:  # noqa: BLE001 — OOM degrades, rest re-raised
+                # only the allocator's verdict degrades: a kernel Mosaic
+                # refuses is a bug, and must not read as a slower engine
+                if not is_oom_error(e) or cand == chain[-1]:
+                    raise
+                import warnings
 
-                    # degrade, but never silently: a genuine bug in an
-                    # engine build would otherwise read as a 4-6x slowdown
-                    warnings.warn(
-                        f"engine {cand!r} failed to build/compile for "
-                        f"{problem.M}x{problem.N} ({type(e).__name__}: "
-                        f"{e}); falling back",
-                        RuntimeWarning,
-                        stacklevel=2,
-                    )
-        raise last_err  # unreachable: the xla build has no capacity gate
+                warnings.warn(
+                    f"engine {cand!r} failed to build/compile for "
+                    f"{problem.M}x{problem.N} out of memory "
+                    f"({type(e).__name__}: {e}); falling back",
+                    RuntimeWarning,
+                    stacklevel=2,
+                )
     if engine == "resident":
         from poisson_ellipse_tpu.ops.resident_pcg import build_resident_solver
 
@@ -600,7 +599,7 @@ def build_solver(
         solver = jax.jit(  # tpulint: disable=TPU004
             lambda a, b, rhs: pcg(
                 problem, a, b, rhs, stencil=stencil, history=history,
-                storage_dtype=storage_dtype,
+                storage_dtype=storage_dtype, interpret=interpret,
             )
         )
         args = (a, b, rhs)

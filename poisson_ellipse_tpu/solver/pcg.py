@@ -129,7 +129,7 @@ def init_state(problem: Problem, a, b, rhs, history: bool = False,
 
 def advance(problem: Problem, a, b, rhs, state, limit=None, stencil: str = "xla",
             history: bool = False, precond=None, storage_dtype=None,
-            recycle: int | None = None):
+            recycle: int | None = None, interpret=None):
     """Advance the PCG carry until convergence/breakdown or iteration
     ``limit`` (defaults to max_iterations). Returns the new carry.
 
@@ -203,13 +203,14 @@ def advance(problem: Problem, a, b, rhs, state, limit=None, stencil: str = "xla"
             # the explicit mixed kernel: storage-width tiles DMA'd to
             # VMEM, upcast there, f32 stencil arithmetic, compute-width out
             apply_stencil = lambda p: apply_a_mixed_pallas(
-                p, a_s, b_s, problem.h1, problem.h2, compute_dtype=dtype
+                p, a_s, b_s, problem.h1, problem.h2, compute_dtype=dtype,
+                interpret=interpret,
             )
         else:
             from poisson_ellipse_tpu.ops.pallas_kernels import apply_a_pallas
 
             apply_stencil = lambda p: apply_a_pallas(
-                p, a, b, problem.h1, problem.h2
+                p, a, b, problem.h1, problem.h2, interpret=interpret
             )
     elif stencil == "xla":
         apply_stencil = lambda p: apply_a(
@@ -312,14 +313,15 @@ def result_of(state) -> PCGResult:
 
 def pcg(problem: Problem, a, b, rhs, stencil: str = "xla",
         history: bool = False, precond=None, storage_dtype=None,
-        x0=None, recycle: int | None = None):
+        x0=None, recycle: int | None = None, interpret=None):
     """Run PCG for pre-assembled coefficients. All inputs (M+1, N+1).
 
     Jit-safe with ``problem`` static; the while_loop carries
     (k, w, r, p, zr, diff, converged, breakdown) entirely on device.
 
     stencil: "xla" (padded-slice arithmetic, XLA-fused) or "pallas" (the
-    explicit VMEM-tiled kernel, ``ops.pallas_kernels.apply_a_pallas``).
+    explicit VMEM-tiled kernel, ``ops.pallas_kernels.apply_a_pallas``;
+    ``interpret`` picks its interpret mode, None = interpret off a TPU).
     The two agree to 1-2 ulps — not bitwise — so iteration counts may
     differ by a step on ill-conditioned grids.
 
@@ -358,7 +360,7 @@ def pcg(problem: Problem, a, b, rhs, stencil: str = "xla",
         init_state(problem, a, b, rhs, history=history, precond=precond,
                    storage_dtype=storage_dtype, x0=x0, recycle=recycle),
         stencil=stencil, history=history, precond=precond,
-        storage_dtype=storage_dtype, recycle=recycle,
+        storage_dtype=storage_dtype, recycle=recycle, interpret=interpret,
     )
     result = result_of(state)
     if recycle:

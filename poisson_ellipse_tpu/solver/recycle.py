@@ -50,7 +50,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 
 from poisson_ellipse_tpu.models.problem import Problem
 from poisson_ellipse_tpu.obs import spectrum
@@ -327,7 +327,6 @@ def build_deflated_sharded_init(
     """
     from jax.sharding import PartitionSpec as P
 
-    from poisson_ellipse_tpu.parallel.compat import shard_map
     from poisson_ellipse_tpu.parallel.halo import halo_extend
     from poisson_ellipse_tpu.parallel.mesh import (
         AXIS_X,

@@ -22,6 +22,11 @@ from poisson_ellipse_tpu.parallel.mesh import virtual_cpu_devices
 
 virtual_cpu_devices(8)
 jax.config.update("jax_enable_x64", True)
+# the persistent compile cache is switched on by the entry points (the
+# harness CLI, bench.py, chip_smoke.py); tests stay hermetic and write
+# nothing into the checkout — subprocesses inherit the variable
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+jax.config.update("jax_enable_compilation_cache", False)
 
 
 # -- tier-1 per-test wall-clock budget ---------------------------------------

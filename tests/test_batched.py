@@ -467,7 +467,7 @@ def test_cli_warmup_subcommand(capsys):
 
     rc = main([
         "warmup", "--grids", "10x10", "--lanes", "1", "--engine",
-        "batched", "--no-persistent", "--json",
+        "batched", "--json",
     ])
     assert rc == 0
     rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])

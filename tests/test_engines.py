@@ -221,30 +221,34 @@ def test_select_engine_scales_with_device_vmem(monkeypatch):
     assert select_engine(Problem(M=4096, N=4096), device=big) == "streamed"
     # a grid beyond the small part's streamed gate takes the xl kernel
     assert select_engine(Problem(M=2400, N=3200), device=small) == "xl"
-    # unknown kind falls back to the measured budgets
+    # a non-TPU device (no platform "tpu") takes the measured budgets
     assert select_engine(
         Problem(M=800, N=1200), device=_Fake("mystery")
     ) == "resident"
 
 
 def test_vmem_capacity_table_and_scaling():
-    """utils.device directly: known kinds hit the table, unknown kinds
-    (including the CPU devices the suite runs on) fall back to the
+    """utils.device directly: the chip's kind hits the table, non-TPU
+    devices (including the CPU devices the suite runs on) take the
     measured 128 MiB part — so a budget scales by exactly 1.0 there —
-    and scaled_vmem_budget is proportional for table entries."""
+    and a TPU of a kind the table does not hold is an error naming the
+    kind, never a guessed budget."""
     from poisson_ellipse_tpu.utils.device import (
         scaled_vmem_budget,
         vmem_capacity_bytes,
     )
 
     class _Fake:
-        def __init__(self, kind):
+        def __init__(self, kind, platform="cpu"):
             self.device_kind = kind
+            self.platform = platform
 
     mib = 1024 * 1024
-    assert vmem_capacity_bytes(_Fake("TPU v5 lite")) == 128 * mib
+    assert vmem_capacity_bytes(_Fake("TPU v5 lite", "tpu")) == 128 * mib
     assert vmem_capacity_bytes(_Fake("not-a-tpu")) == 128 * mib
     assert scaled_vmem_budget(114 * mib, _Fake("unknown")) == 114 * mib
+    with pytest.raises(ValueError, match="TPU v9 mystery"):
+        vmem_capacity_bytes(_Fake("TPU v9 mystery", "tpu"))
     # the suite's default (CPU) device takes the fallback too
     assert scaled_vmem_budget(125 * mib) == 125 * mib
 
@@ -392,7 +396,7 @@ def test_roofline_passes_model():
     p_small = Problem(M=40, N=40)
     assert passes_per_iter(p_small, "resident") == 0.0
     assert passes_per_iter(p_small, "xla") == 13.0
-    assert passes_per_iter(p_small, "fused") == 17.0
+    assert passes_per_iter(p_small, "fused") == 16.0
     # streamed: a fully resident plan streams nothing
     assert passes_per_iter(p_small, "streamed") == 0.0
     big = Problem(M=2400, N=3200)
@@ -449,6 +453,20 @@ def test_auto_falls_back_when_selected_engine_fails(monkeypatch):
     # explicit requests still fail loudly
     with pytest.raises(RuntimeError, match="simulated"):
         build_solver(problem, "resident")
+
+
+def test_auto_raises_when_selected_engine_is_refused(monkeypatch):
+    """Only memory exhaustion degrades: a kernel the compiler refuses
+    for any other reason is a bug, and auto must raise it rather than
+    hide it behind a slower engine."""
+    import poisson_ellipse_tpu.ops.resident_pcg as rp
+
+    def refused(*a, **k):
+        raise RuntimeError("Mosaic failed to compile TPU kernel (simulated)")
+
+    monkeypatch.setattr(rp, "build_resident_solver", refused)
+    with pytest.raises(RuntimeError, match="simulated"):
+        build_solver(Problem(M=40, N=40), "auto")
 
 
 @pytest.mark.parametrize("cfg", [

@@ -15,7 +15,7 @@ Four layers of assertion, mirroring the tentpole's claims:
 - **autotuner closed loop**: selection is a pure function of the
   telemetry (same telemetry → same config), the static default is
   never beaten by prediction noise (the margin rule), configs persist
-  and reload deterministically next to the XLA cache, and
+  and reload deterministically from the registry file, and
   ``build_solver(engine="auto")`` / the serve scheduler consult them.
 """
 

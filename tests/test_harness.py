@@ -425,7 +425,11 @@ def test_roofline_line_vmem_resident_wording():
     assert "VMEM-resident" in line and "0 GB/s" not in line
 
 
-def test_acceptance_gate_passes_on_cpu():
+# one grid per case (the sharded rows run at it too): the whole matrix
+# in one test sat at the tier-1 per-test budget
+@pytest.mark.parametrize("grid", [(10, 10), (20, 20), (40, 40)],
+                         ids=lambda g: f"{g[0]}x{g[1]}")
+def test_acceptance_gate_passes_on_cpu(grid):
     # on CPU the Pallas engines run in interpret mode; the oracle/contract
     # logic is identical, and the real-compile value comes from running
     # the same module on the chip (python -m ...harness.acceptance)
@@ -433,6 +437,8 @@ def test_acceptance_gate_passes_on_cpu():
     import io
 
     buf = io.StringIO()
-    assert run_acceptance(headline=False, out=buf) is True
+    assert run_acceptance(headline=False, out=buf, grids=(grid,)) is True, (
+        buf.getvalue()
+    )
     text = buf.getvalue()
     assert "ACCEPTANCE PASS" in text and "FAIL" not in text

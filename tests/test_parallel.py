@@ -13,7 +13,7 @@ import pytest
 from jax.sharding import Mesh
 
 from poisson_ellipse_tpu.models.problem import Problem
-from poisson_ellipse_tpu.parallel.compat import shard_map
+from jax import shard_map
 from poisson_ellipse_tpu.parallel.halo import halo_extend
 from poisson_ellipse_tpu.parallel.mesh import (
     choose_process_grid,

@@ -168,8 +168,8 @@ def test_chunk_boundary_on_replacement_iteration():
 
 @pytest.mark.parametrize("mesh_shape", [(1, 2), (2, 2)])
 def test_sharded_pipelined_matches_single_chip(mesh_shape):
-    """The one-psum sharded variant on a CPU mesh (through the
-    ``parallel.compat`` shard_map shim): iters within ±2 of the sharded
+    """The one-psum sharded variant on a CPU mesh (through
+    ``jax.shard_map``): iters within ±2 of the sharded
     xla path and elementwise agreement with the single-chip pipelined
     solve."""
     from poisson_ellipse_tpu.parallel.pcg_sharded import solve_sharded

@@ -415,3 +415,21 @@ def test_harness_inspect_cli_reports_sstep_cadence(capsys):
     out = capsys.readouterr().out
     assert rc == 0
     assert "storage bfloat16" in out
+
+
+@pytest.mark.parametrize("ceiling, ok", [(68, True), (55, False)])
+def test_acceptance_sstep_row_gates_f32_gram_on_its_band(monkeypatch,
+                                                         ceiling, ok):
+    """x64 off (the chip's default) the acceptance s-step row holds the
+    count to [oracle, F32_GRAM_CEILINGS] — the CPU's f32-Gram count at
+    40² (61) is inside the committed band and outside a tighter one."""
+    from poisson_ellipse_tpu.harness import acceptance
+
+    monkeypatch.setitem(acceptance.F32_GRAM_CEILINGS, (40, 40), ceiling)
+    with jax.enable_x64(False):
+        row_ok, note = acceptance._row("sstep", 40, 40, 50)
+    assert row_ok is ok, note
+    assert f"..{ceiling}, f32 Gram" in note
+    # with x64 (the suite's default) the classical oracle applies exactly
+    assert acceptance._row("sstep", 40, 40, 50) == (
+        True, "iters=50 (oracle 50)")

@@ -6,7 +6,7 @@ rounds metric by metric against named tolerances, exiting nonzero with
 the offending metric spelled out — a perf regression becomes a failing
 check, not an archaeology project:
 
-    python tools/bench_compare.py BENCH_r04.json BENCH_r05.json
+    python tools/bench_compare.py OLD.json NEW.json
     python tools/bench_compare.py              # newest two rounds
 
 Compared, where both rounds carry them (absence is skipped and noted —
@@ -78,6 +78,7 @@ import json
 import os
 import re
 import sys
+import tomllib
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -141,22 +142,28 @@ _ROUND_RE = re.compile(r"BENCH_r(\d+)\.json$")
 def load_tolerances(root: str = ROOT) -> dict:
     """DEFAULT_TOLERANCES overlaid with `[tool.bench_compare]`.
 
-    Reuses the tpulint loader's tomllib-with-subset-fallback reader
-    (this interpreter may predate tomllib); the fallback stores floats
-    as strings, so values are coerced here.
+    A pyproject that does not parse is an error naming the file and the
+    offending key: gating on the defaults instead would hide a typo'd
+    bound.
     """
     tol = dict(DEFAULT_TOLERANCES)
     pyproject = os.path.join(root, "pyproject.toml")
     if not os.path.exists(pyproject):
         return tol
-    try:
-        from poisson_ellipse_tpu.lint import _read_pyproject
+    from poisson_ellipse_tpu.lint import _read_pyproject
 
-        table = _read_pyproject(pyproject).get("tool", {}).get(
-            "bench_compare", {}
-        )
-    except Exception:  # loader unavailable: the defaults still gate
-        return tol
+    try:
+        doc = _read_pyproject(pyproject)
+    except tomllib.TOMLDecodeError as e:
+        # tomllib names only the position: "... (at line 2, column 16)"
+        m = re.search(r"at line (\d+)", str(e))
+        line = ""
+        if m:
+            with open(pyproject, encoding="utf-8") as f:
+                line = f.read().splitlines()[int(m.group(1)) - 1]
+        key = line.partition("=")[0].strip() or "?"
+        raise SystemExit(f"{pyproject}: key {key}: {e}") from e
+    table = doc.get("tool", {}).get("bench_compare", {})
     for key in tol:
         if key in table:
             try:

@@ -289,7 +289,7 @@ def autotune_lines(rec: dict) -> list[str]:
     lines = [
         "Telemetry-driven autotuning (`runtime.autotune`: per-shape "
         "configs scored from measured κ/Ritz predictions and GB/s, "
-        "persisted next to the XLA cache; a tuned config that loses to "
+        "persisted in the checkout; a tuned config that loses to "
         "the static default fails the `autotune-pct` gate):",
         "",
         "| Grid | tuned engine | tuned | static default | verdict |",
