@@ -7,10 +7,11 @@ Three legs, mirroring what a production solver service has to expose:
   ring buffers: convergence curves with zero host syncs, surfaced as
   ``solve(..., history=True)`` on the classical, fused, pipelined and
   sharded engines.
-- :mod:`.trace` + :mod:`.metrics` — dependency-free structured JSONL
-  run tracing (run ids, monotonic phase spans, counters/gauges) behind
-  ``--trace FILE`` / ``POISSON_TRACE=``; ``utils.timing.PhaseTimer`` is
-  a thin shim over it.
+- :mod:`.trace` + :mod:`.metrics` — structured JSONL run tracing (run
+  ids, monotonic phase spans, counters/gauges) behind ``--trace FILE`` /
+  ``POISSON_TRACE=``, whose spans are also ``jax.profiler`` annotations
+  (``poisson:<name>``); ``utils.timing.PhaseTimer`` is a thin shim over
+  it.
 - :mod:`.static_cost` — compile-time accounting from the jaxpr and
   XLA's cost analysis: psum/ppermute per iteration, estimated FLOPs and
   HBM bytes, measured-vs-modeled roofline columns — the layer that

@@ -1,4 +1,4 @@
-"""Structured run tracing: dependency-free JSONL events with one schema.
+"""Structured run tracing: JSONL events with one schema.
 
 The reference publishes its runs as free-form stdout tables (rank-0
 printf blocks, ``poisson_mpi_cuda2.cu:1000-1034``); this framework's
@@ -12,9 +12,15 @@ and one validated schema, so traces diff, grep and aggregate cleanly.
 Activation is explicit (``--trace FILE`` on the harness CLI, or
 :func:`start` from code) or ambient (the ``POISSON_TRACE`` environment
 variable names the sink file); when no tracer is active every emitting
-helper is a no-op, so instrumented code pays nothing. Nothing here
-imports beyond the standard library — the tracer must work in the
-leanest headless environment the solvers do.
+helper is a no-op, so instrumented code pays nothing.
+
+:func:`span` also opens a ``jax.profiler.TraceAnnotation`` named
+``poisson:<name>`` (``PROFILER_PREFIX``), its fields as the annotation's
+arguments, whether or not a JSONL tracer is active: while a profiler
+trace runs, the program's spans land on the profiler's clock beside the
+device's ops, so an idle gap on the device can be named by the span the
+host was in. With neither running, a span costs one annotation's
+construction. Nothing else here imports beyond the standard library.
 
 Record schema (one JSON object per line; :func:`validate_record`):
 
@@ -51,6 +57,8 @@ import sys
 import time
 import uuid
 
+from jax.profiler import TraceAnnotation
+
 # v2 added the optional top-level ``lane`` key (lane-addressed batched
 # events); v3 the optional ``request_id`` key (request-addressed serving
 # events); v1/v2 records remain valid — see VALID_VERSIONS
@@ -70,6 +78,11 @@ _ALLOWED_KEYS = frozenset(
 )
 
 ENV_VAR = "POISSON_TRACE"
+
+# the program's spans in a profiler trace are named PROFILER_PREFIX + name
+PROFILER_PREFIX = "poisson:"
+# each span name's profiler name, joined once: a span pays a lookup
+_PROFILER_NAMES: dict[str, str] = {}
 
 
 class Tracer:
@@ -140,9 +153,6 @@ class Tracer:
             request_id=request_id,
         )
 
-    def span(self, name: str, **fields) -> "_Span":
-        return _Span(self, name, fields)
-
     @property
     def closed(self) -> bool:
         """True once the sink can no longer accept records (late
@@ -157,19 +167,27 @@ class Tracer:
 
 class _Span:
     """Context manager emitting one ``span`` record at exit (monotonic
-    duration; ``t`` is the span's start offset, as the schema table says)."""
+    duration; ``t`` is the span's start offset, as the schema table says)
+    inside ``annotation``, the profiler's view of the same span.
+    ``lane`` and ``request_id`` fields go to the record's top-level
+    keys, as on events."""
 
-    def __init__(self, tracer: Tracer, name: str, fields: dict):
+    def __init__(self, tracer: Tracer, name: str, fields: dict,
+                 annotation: TraceAnnotation):
         self.tracer = tracer
         self.name = name
         self.fields = fields
+        self.annotation = annotation
 
     def __enter__(self):
+        self.annotation.__enter__()
         self._start = time.monotonic()
         return self
 
     def __exit__(self, exc_type, *exc):
         fields = dict(self.fields)
+        lane = fields.pop("lane", None)
+        request_id = fields.pop("request_id", None)
         if exc_type is not None:
             fields["error"] = exc_type.__name__
         self.tracer.emit(
@@ -180,7 +198,10 @@ class _Span:
             # t is the span's START offset (the schema table's contract),
             # so spans sort and nest by when they began, not ended
             t=self._start - self.tracer._t0,
+            lane=lane,
+            request_id=request_id,
         )
+        self.annotation.__exit__(exc_type, *exc)
         return False
 
 
@@ -225,37 +246,19 @@ def active() -> Tracer | None:
     return _active
 
 
-class _NullSpan:
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 def span(name: str, **fields):
-    """A span on the ambient tracer, or a no-op context when inactive."""
+    """A span on the profiler's clock (``poisson:<name>``, ``fields`` as
+    its arguments) and, while a tracer is active, on the ambient tracer.
+    Spans nest by their brackets: a span's parent is the one enclosing
+    it. Spans of one request carry its ``request_id`` (and ``lane``)."""
+    full = _PROFILER_NAMES.get(name)
+    if full is None:
+        full = _PROFILER_NAMES[name] = PROFILER_PREFIX + name
+    annotation = TraceAnnotation(full, **fields)
     tracer = active()
-    return tracer.span(name, **fields) if tracer else _NULL_SPAN
-
-
-def span_event(name: str, dur: float, **fields) -> None:
-    """Emit an already-measured span (the PhaseTimer shim's entry).
-
-    The span ended "now", so its schema-mandated start offset is now
-    minus ``dur`` — same t convention as a live ``span()`` bracket."""
-    tracer = active()
-    if tracer:
-        tracer.emit(
-            "span",
-            name,
-            dur=dur,
-            fields=fields or None,
-            t=(time.monotonic() - tracer._t0) - dur,
-        )
+    if tracer is None:
+        return annotation
+    return _Span(tracer, name, fields, annotation)
 
 
 def event(name: str, lane: int | None = None,

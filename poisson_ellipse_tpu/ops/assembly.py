@@ -28,6 +28,7 @@ import numpy as np
 
 from poisson_ellipse_tpu.models import ellipse
 from poisson_ellipse_tpu.models.problem import Problem
+from poisson_ellipse_tpu.obs import trace as obs_trace
 
 # Tolerances from the reference's blend law (stage0/Withoutopenmp1.cpp:53-54).
 _FULL_TOL = 1e-9
@@ -137,28 +138,32 @@ def assemble_numpy(problem: Problem, geometry=None, theta=None):
 
     Public API: the sharded solver pads/casts/lays these arrays out over
     the mesh. Uses the same closed forms as the traced path via
-    ``_coefficients_xp(…, xp=numpy)``.
+    ``_coefficients_xp(…, xp=numpy)``. Traced as an ``assembly.host``
+    span: every engine's build and every serve refill assembles here.
     """
-    M, N = problem.M, problem.N
-    gi = np.arange(M + 1, dtype=np.float64)
-    gj = np.arange(N + 1, dtype=np.float64)
-    x = problem.a1 + gi * problem.h1
-    y = problem.a2 + gj * problem.h2
-    if geometry is None:
-        a, b = _coefficients_xp(problem, x, y, np)
-        inside = ellipse.is_in_d(x[:, None], y[None, :])
-    else:
-        a, b, inside = _geometry_coefficients(problem, geometry, theta, x, y)
+    with obs_trace.span("assembly.host"):
+        M, N = problem.M, problem.N
+        gi = np.arange(M + 1, dtype=np.float64)
+        gj = np.arange(N + 1, dtype=np.float64)
+        x = problem.a1 + gi * problem.h1
+        y = problem.a2 + gj * problem.h2
+        if geometry is None:
+            a, b = _coefficients_xp(problem, x, y, np)
+            inside = ellipse.is_in_d(x[:, None], y[None, :])
+        else:
+            a, b, inside = _geometry_coefficients(problem, geometry, theta,
+                                                  x, y)
 
-    valid = ((gi >= 1) & (gi <= M))[:, None] & ((gj >= 1) & (gj <= N))[None, :]
-    a = np.where(valid, a, 0.0)
-    b = np.where(valid, b, 0.0)
+        valid = (((gi >= 1) & (gi <= M))[:, None]
+                 & ((gj >= 1) & (gj <= N))[None, :])
+        a = np.where(valid, a, 0.0)
+        b = np.where(valid, b, 0.0)
 
-    interior = ((gi >= 1) & (gi <= M - 1))[:, None] & (
-        (gj >= 1) & (gj <= N - 1)
-    )[None, :]
-    rhs = np.where(inside & interior, problem.f_val, 0.0)
-    return a, b, rhs
+        interior = ((gi >= 1) & (gi <= M - 1))[:, None] & (
+            (gj >= 1) & (gj <= N - 1)
+        )[None, :]
+        rhs = np.where(inside & interior, problem.f_val, 0.0)
+        return a, b, rhs
 
 
 # memo over the quadrature assembly, keyed (problem, geometry, theta):
@@ -179,7 +184,6 @@ def _geometry_coefficients(problem: Problem, geometry, theta, x, y):
     bisection quadrature, the degenerate-cut clamp, the same blend law.
     Host f64 only (the traced path stays closed-form ellipse)."""
     from poisson_ellipse_tpu.geom import quadrature, sdf as geom_sdf
-    from poisson_ellipse_tpu.obs import trace as obs_trace
 
     if theta is None:
         theta = quadrature.DEFAULT_THETA

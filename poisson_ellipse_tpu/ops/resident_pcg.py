@@ -249,9 +249,12 @@ def build_resident_solver(problem: Problem, dtype=jnp.float32,
             vmem_limit_bytes=scaled_vmem_budget(_VMEM_LIMIT)
         ),
         interpret=interpret,
+        name="resident_pcg",
     )
 
-    def solver(*operands):
+    # the jitted function's name is the custom call's name in the device
+    # trace: one stable name per engine
+    def resident_pcg(*operands):
         w_pad, iters, diff, flags = call(*operands)
         return PCGResult(
             w=w_pad[:g1, :g2],
@@ -261,7 +264,7 @@ def build_resident_solver(problem: Problem, dtype=jnp.float32,
             breakdown=flags[1].astype(bool),
         )
 
-    return jax.jit(solver), args
+    return jax.jit(resident_pcg), args
 
 
 def solve_resident(problem: Problem, dtype=jnp.float32,

@@ -657,9 +657,12 @@ def build_streamed_solver(problem: Problem, dtype=jnp.float32,
             vmem_limit_bytes=scaled_vmem_budget(_VMEM_LIMIT)
         ),
         interpret=interpret,
+        name="streamed_pcg",
     )
 
-    def solver(dinv, a, b, r0):
+    # the jitted function's name is the custom call's name in the device
+    # trace: one stable name per engine
+    def streamed_pcg(dinv, a, b, r0):
         w_pad, iters, diff, flags, _ap = call(dinv, a, b, r0)
         return PCGResult(
             w=w_pad[:g1, :g2],
@@ -672,7 +675,7 @@ def build_streamed_solver(problem: Problem, dtype=jnp.float32,
     # no donation: build-once-call-many — callers re-feed these operands
     # every dispatch (bench --repeat protocol)
     # tpulint: disable=TPU004
-    return jax.jit(solver), args
+    return jax.jit(streamed_pcg), args
 
 
 def solve_streamed(problem: Problem, dtype=jnp.float32,

@@ -481,12 +481,15 @@ def build_xl_solver(problem: Problem, dtype=jnp.float32, interpret=None,
             vmem_limit_bytes=scaled_vmem_budget(_VMEM_LIMIT)
         ),
         interpret=interpret,
+        name="xl_pcg",
     )
 
     if _debug_raw:
         return jax.jit(call), args
 
-    def solver(dinv, a, b, r0):
+    # the jitted function's name is the custom call's name in the device
+    # trace: one stable name per engine
+    def xl_pcg(dinv, a, b, r0):
         w_pad, iters, diff, flags, _z, _p, _ap = call(dinv, a, b, r0)
         return PCGResult(
             w=w_pad[:g1, :g2],
@@ -499,7 +502,7 @@ def build_xl_solver(problem: Problem, dtype=jnp.float32, interpret=None,
     # no donation: build-once-call-many — callers re-feed these operands
     # every dispatch (bench --repeat protocol)
     # tpulint: disable=TPU004
-    return jax.jit(solver), args
+    return jax.jit(xl_pcg), args
 
 
 def solve_xl(problem: Problem, dtype=jnp.float32, interpret=None) -> PCGResult:

@@ -657,53 +657,63 @@ class Scheduler:
     def step(self) -> bool:
         """One chunk across every active bucket: shed expired queued
         requests, refill free lanes, inject due faults, advance, retire.
-        Returns True while work remains (in flight or queued)."""
-        now = self.clock()
-        for req in self.queue.expire(now):
-            self._finish_queued(
-                req, "deadline-miss", detail="expired-in-queue"
-            )
-        self._admit_replay_wave()
-        self._fill_lanes()
-        # lanes just drained the queue — top it back up so the next
-        # boundary dispatches from a full line, not a replay-starved one
-        self._admit_replay_wave()
-        for ctx in list(self._ctxs.values()):
-            if not ctx.active or ctx not in self._ctxs.values():
-                continue
-            self._apply_faults(ctx)
-            if not ctx.active or ctx not in self._ctxs.values():
-                continue
-            k = int(ctx.state[0])
-            # the chunk stops early at the nearest per-request iteration
-            # cap (the FaultPlan.next_stop idiom): caps land exactly,
-            # not at the next multiple of `chunk`
-            limit_val = min(k + (ctx.chunk or self.chunk), ITER_CEILING)
-            for slot in ctx.slots:
-                if slot is not None:
-                    limit_val = min(
-                        limit_val,
-                        slot.base_k + slot.req.problem.max_iterations,
-                    )
-            limit = jnp.asarray(max(limit_val, k + 1), jnp.int32)
-            try:
-                ctx.state = ctx.fn(
-                    ctx.a3, ctx.b3, ctx.mask, ctx.h1, ctx.h2, ctx.delta,
-                    ctx.state, limit,
-                )
-            except Exception as e:  # noqa: BLE001 — classified; unknowns re-raised
-                from poisson_ellipse_tpu.resilience.errors import (
-                    classify_error,
-                )
+        Returns True while work remains (in flight or queued).
 
-                if classify_error(e) != "device-loss":
-                    raise
-                self._degrade_mesh("device-loss", getattr(e, "device", None))
-                continue
-            self._boundary(ctx)
-        return bool(
-            len(self.queue) or self._replay_backlog
-        ) or any(c.active for c in self._ctxs.values())
+        Traced as ``serve.step``, holding a ``serve.refill`` per lane
+        swapped in, a ``serve.advance`` (faults, limit, dispatch) and a
+        ``serve.boundary`` (flag reads, retirements, rebase) per bucket."""
+        with obs_trace.span("serve.step"):
+            now = self.clock()
+            for req in self.queue.expire(now):
+                self._finish_queued(
+                    req, "deadline-miss", detail="expired-in-queue"
+                )
+            self._admit_replay_wave()
+            self._fill_lanes()
+            # lanes just drained the queue — top it back up so the next
+            # boundary dispatches from a full line, not a replay-starved one
+            self._admit_replay_wave()
+            for ctx in list(self._ctxs.values()):
+                if not ctx.active or ctx not in self._ctxs.values():
+                    continue
+                with obs_trace.span("serve.advance"):
+                    self._apply_faults(ctx)
+                    if not ctx.active or ctx not in self._ctxs.values():
+                        continue
+                    k = int(ctx.state[0])
+                    # the chunk stops early at the nearest per-request
+                    # iteration cap (the FaultPlan.next_stop idiom): caps
+                    # land exactly, not at the next multiple of `chunk`
+                    limit_val = min(k + (ctx.chunk or self.chunk),
+                                    ITER_CEILING)
+                    for slot in ctx.slots:
+                        if slot is not None:
+                            limit_val = min(
+                                limit_val,
+                                slot.base_k + slot.req.problem.max_iterations,
+                            )
+                    limit = jnp.asarray(max(limit_val, k + 1), jnp.int32)
+                    try:
+                        ctx.state = ctx.fn(
+                            ctx.a3, ctx.b3, ctx.mask, ctx.h1, ctx.h2,
+                            ctx.delta, ctx.state, limit,
+                        )
+                    except Exception as e:  # noqa: BLE001 — classified; unknowns re-raised
+                        from poisson_ellipse_tpu.resilience.errors import (
+                            classify_error,
+                        )
+
+                        if classify_error(e) != "device-loss":
+                            raise
+                        self._degrade_mesh(
+                            "device-loss", getattr(e, "device", None)
+                        )
+                        continue
+                with obs_trace.span("serve.boundary"):
+                    self._boundary(ctx)
+            return bool(
+                len(self.queue) or self._replay_backlog
+            ) or any(c.active for c in self._ctxs.values())
 
     def drain(self, max_steps: int = 100_000) -> dict[str, ServeResult]:
         """Step until every admitted request is terminal. When the only
@@ -787,7 +797,9 @@ class Scheduler:
             if lane is None:
                 deferred.append(req)
                 continue
-            self._refill_lane(ctx, lane, req)
+            with obs_trace.span("serve.refill", request_id=req.request_id,
+                                lane=lane):
+                self._refill_lane(ctx, lane, req)
         for req in reversed(deferred):
             self.queue.push_front(req)
 
@@ -944,7 +956,8 @@ class Scheduler:
     # -- retirement ----------------------------------------------------------
 
     def _boundary(self, ctx: _BatchCtx) -> None:
-        """The chunk-boundary host read: retire finished lanes.
+        """The chunk-boundary host read: retire finished lanes, each in a
+        ``serve.retire`` span whose ``outcome`` is what the boundary saw.
         Ordering is the deadline contract — converged lanes first (a
         result beats a miss at the same boundary), then fault
         retirement into the retry ladder, then deadline cancels, then
@@ -962,26 +975,36 @@ class Scheduler:
             req_iters = int(iters[lane]) - slot.base_k
             diff = float(diffs[lane])
             if conv[lane]:
-                if req.grad:
+                outcome = "converged"
+            elif quar[lane] or bd[lane]:
+                outcome = "lane-quarantine" if quar[lane] else "breakdown"
+            elif req.deadline is not None and now > req.deadline:
+                outcome = "deadline-miss"
+            elif req_iters >= req.problem.max_iterations:
+                outcome = "cap"
+            else:
+                continue
+            with obs_trace.span("serve.retire", request_id=req.request_id,
+                                lane=lane, outcome=outcome):
+                if outcome == "converged" and req.grad:
                     self._grad_boundary(ctx, lane, slot, req_iters, diff)
-                else:
+                elif outcome == "converged":
                     self._finish(
                         ctx, lane, slot, "completed", iters=req_iters,
                         diff=diff, converged=True,
                     )
-            elif quar[lane] or bd[lane]:
-                cause = "lane-quarantine" if quar[lane] else "breakdown"
-                self._park_lane(ctx, lane)
-                self._retry_or_fallback(slot, cause)
-            elif req.deadline is not None and now > req.deadline:
-                self._finish(
-                    ctx, lane, slot, "deadline-miss", iters=req_iters,
-                    diff=diff, partial=True, detail="expired-mid-solve",
-                )
-            elif req_iters >= req.problem.max_iterations:
-                self._finish(
-                    ctx, lane, slot, "cap", iters=req_iters, diff=diff
-                )
+                elif outcome == "deadline-miss":
+                    self._finish(
+                        ctx, lane, slot, "deadline-miss", iters=req_iters,
+                        diff=diff, partial=True, detail="expired-mid-solve",
+                    )
+                elif outcome == "cap":
+                    self._finish(
+                        ctx, lane, slot, "cap", iters=req_iters, diff=diff
+                    )
+                else:
+                    self._park_lane(ctx, lane)
+                    self._retry_or_fallback(slot, outcome)
         # rebase the batch's global clock: k only moves forward, and a
         # hot bucket on a long-lived server would otherwise walk it
         # into ITER_CEILING (~2^30 iterations ≈ 2M solves) and wedge —

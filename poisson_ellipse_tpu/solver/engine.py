@@ -82,6 +82,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from poisson_ellipse_tpu.models.problem import Problem
+from poisson_ellipse_tpu.obs import trace as obs_trace
 from poisson_ellipse_tpu.ops import assembly
 from poisson_ellipse_tpu.solver.pcg import PCGResult, pcg
 
@@ -372,7 +373,23 @@ def build_solver(
     → streamed → xl → xla) with a ``RuntimeWarning``. Any other failure
     — a kernel Mosaic refuses — is raised: it is a bug, not a capacity
     fact. Explicitly requested engines always fail loudly.
+
+    Traced as one ``engine.build`` span per call (``engine`` the name
+    asked for); ``auto``'s probe is its ``engine.lower`` and
+    ``engine.compile`` spans, the host assembly ``assembly.host``.
     """
+    with obs_trace.span("engine.build", engine=engine):
+        return build_solver_body(
+            problem, engine, dtype, interpret, history, lanes, geometry,
+            theta, validate_geometry, storage_dtype, sstep_s, tuned_knobs,
+        )
+
+
+def build_solver_body(problem, engine, dtype, interpret, history, lanes,
+                      geometry, theta, validate_geometry, storage_dtype,
+                      sstep_s, tuned_knobs):
+    """``build_solver``'s body: ``auto`` builds each rung of its ladder
+    through here, inside the one ``engine.build`` span of its call."""
     if lanes != 1 and engine not in BATCHED_ENGINES:
         raise ValueError(
             f"engine {engine!r} runs one solve per dispatch; lanes={lanes} "
@@ -471,15 +488,19 @@ def build_solver(
         for cand in chain:
             try:
                 # the gate already ran above — don't re-validate per rung
-                solver, args, _ = build_solver(
-                    problem, cand, dtype, interpret, geometry=geometry,
-                    theta=theta, validate_geometry=False,
+                solver, args, _ = build_solver_body(
+                    problem, cand, dtype, interpret, history=False, lanes=1,
+                    geometry=geometry, theta=theta, validate_geometry=False,
+                    storage_dtype=None, sstep_s=4, tuned_knobs=None,
                 )
                 if cand != "xla" and jax.default_backend() == "tpu":
                     # force Mosaic compilation now, where we can catch it.
                     # The jit dispatch cache is shared with this AOT
                     # lowering, so the probe costs nothing extra.
-                    solver.lower(*args).compile()
+                    with obs_trace.span("engine.lower", engine=cand):
+                        lowered = solver.lower(*args)
+                    with obs_trace.span("engine.compile", engine=cand):
+                        lowered.compile()
                 return solver, args, cand
             except Exception as e:  # noqa: BLE001 — OOM degrades, rest re-raised
                 # only the allocator's verdict degrades: a kernel Mosaic
@@ -493,7 +514,7 @@ def build_solver(
                     f"{problem.M}x{problem.N} out of memory "
                     f"({type(e).__name__}: {e}); falling back",
                     RuntimeWarning,
-                    stacklevel=2,
+                    stacklevel=3,
                 )
     if engine == "resident":
         from poisson_ellipse_tpu.ops.resident_pcg import build_resident_solver

@@ -20,14 +20,17 @@ it would destroy the very fusion being measured. So timing splits in two:
   per-phase accumulators, measured without slowing the production loop.
 
 ``PhaseTimer`` is a thin shim over the structured trace layer
-(``obs.trace``): every region it closes is also emitted as a ``span``
-record (``phase:<name>``) into the ambient JSONL trace when one is
-active, so the human report and the machine trace come from the same
-measurement.
+(``obs.trace``), so the human report and the machine trace come from the
+same measurement: a ``phase()`` region is a live ``phase:<name>`` span
+(on the profiler's clock, and in the ambient JSONL trace when one is
+active); a duration measured elsewhere and handed to ``add()`` has no
+start to claim, so it is a ``phase:<name>`` event with a ``seconds``
+field.
 """
 
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass, field
 
@@ -64,14 +67,21 @@ class PhaseTimer:
 
     totals: dict[str, float] = field(default_factory=dict)
 
+    @contextlib.contextmanager
     def phase(self, name: str):
-        return _Region(self, name)
+        with _trace.span(f"phase:{name}"):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                self._accumulate(name, time.perf_counter() - t0)
 
     def add(self, name: str, seconds: float) -> None:
+        self._accumulate(name, seconds)
+        _trace.event(f"phase:{name}", seconds=seconds)
+
+    def _accumulate(self, name: str, seconds: float) -> None:
         self.totals[name] = self.totals.get(name, 0.0) + seconds
-        # the shim half: the same measurement lands in the JSONL trace
-        # (no-op when tracing is inactive)
-        _trace.span_event(f"phase:{name}", seconds)
 
     def report(self, out=None) -> str:
         """Name-sorted rows with a share-of-total column.
@@ -91,17 +101,3 @@ class PhaseTimer:
         if out is not None:
             print(text, file=out)
         return text
-
-
-class _Region:
-    def __init__(self, timer: PhaseTimer, name: str):
-        self.timer = timer
-        self.name = name
-
-    def __enter__(self):
-        self.t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self.timer.add(self.name, time.perf_counter() - self.t0)
-        return False

@@ -71,3 +71,23 @@ def test_engine_compiles_a_mosaic_kernel_for_v5e(one_chip, engine, grid):
              for x in args]
     compiled = solver.lower(*specs).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("engine,grid", [
+    ("resident", (40, 60)),
+    ("streamed", (400, 600)),
+    ("xl", (400, 600)),
+])
+def test_megakernel_keeps_its_name_in_the_compiled_program(one_chip, engine,
+                                                           grid):
+    """The device trace names a custom call by its HLO instruction, which
+    takes the jitted function's name: each engine's kernel has its own."""
+    solver, args, _ = build_solver(
+        Problem(M=grid[0], N=grid[1]), engine, jnp.float32, interpret=False
+    )
+    specs = [jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+             for x in args]
+    text = solver.lower(*specs).compile().as_text()
+    calls = [line.split(" = ", 1)[0].strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls == [f"%{engine}_pcg.1"]

@@ -617,6 +617,9 @@ def test_phase_timer_report_zero_guard_and_stable_order():
 
 
 def test_phase_timer_is_a_trace_shim(tmp_path):
+    """A ``phase()`` region is a live span; a duration measured
+    elsewhere and ``add()``-ed has no start to claim, so it is an
+    event carrying its seconds."""
     from poisson_ellipse_tpu.utils.timing import PhaseTimer
 
     path = tmp_path / "phases.jsonl"
@@ -627,10 +630,130 @@ def test_phase_timer_is_a_trace_shim(tmp_path):
     t.add("solver", 1.5)
     obs_trace.stop()
     recs = obs_trace.read_jsonl(path)
-    spans = {r["name"]: r for r in recs if r["kind"] == "span"}
-    assert set(spans) == {"phase:init", "phase:solver"}
-    assert spans["phase:solver"]["dur"] == 1.5
+    phases = {r["name"]: r for r in recs if r["name"].startswith("phase:")}
+    assert set(phases) == {"phase:init", "phase:solver"}
+    assert phases["phase:init"]["kind"] == "span"
+    assert phases["phase:init"]["dur"] >= 0
+    assert phases["phase:solver"]["kind"] == "event"
+    assert phases["phase:solver"]["fields"] == {"seconds": 1.5}
+    assert "dur" not in phases["phase:solver"]
+    assert set(t.totals) == {"init", "solver"} and t.totals["solver"] == 1.5
     assert obs_trace.validate_file(path) == []
+
+
+# ------------------------------------------------------- program spans
+
+
+SERVE_SPANS = ("serve.step", "serve.refill", "serve.advance",
+               "serve.boundary", "serve.retire")
+
+
+def _profiled_spans(trace_dir) -> dict:
+    """The program's spans in the newest profiler trace under
+    ``trace_dir``, by name without the prefix: [(start_ns, end_ns,
+    arguments)]."""
+    import collections
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(
+        str(trace_dir), "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    spans = collections.defaultdict(list)
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(obs_trace.PROFILER_PREFIX):
+                    name = e.name[len(obs_trace.PROFILER_PREFIX):]
+                    spans[name].append(
+                        (e.start_ns, e.end_ns, dict(e.stats)))
+    return spans
+
+
+def _inside(span, parents) -> bool:
+    return any(s <= span[0] and span[1] <= e for s, e, _ in parents)
+
+
+def test_program_spans_nest_on_the_profiler_clock(tmp_path, monkeypatch):
+    """Three requests through the scheduler and one ``auto`` build under
+    a profiler trace: every span the serving and build paths open lands
+    on the profiler's clock, prefixed, nested as the code brackets them
+    and addressed to its request."""
+    from poisson_ellipse_tpu.serve import Scheduler
+    from poisson_ellipse_tpu.solver.engine import build_solver
+
+    sched = Scheduler(lanes=2, chunk=8)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for i in range(3):
+            assert sched.submit(Problem(M=20, N=20),
+                                request_id=f"r{i}") is None
+        results = sched.drain()
+        # auto's AOT probe runs on a TPU backend only
+        with monkeypatch.context() as m:
+            m.setattr(jax, "default_backend", lambda: "tpu")
+            _, _, engine = build_solver(Problem(M=20, N=20), "auto",
+                                        interpret=True)
+    finally:
+        jax.profiler.stop_trace()
+    assert engine == "resident"
+    assert {r.outcome for r in results.values()} == {"completed"}
+
+    spans = _profiled_spans(tmp_path)
+    assert set(spans) == {*SERVE_SPANS, "assembly.host", "engine.build",
+                          "engine.lower", "engine.compile"}
+    for name in SERVE_SPANS[1:]:
+        assert all(_inside(s, spans["serve.step"]) for s in spans[name])
+    assert all(_inside(s, spans["serve.boundary"])
+               for s in spans["serve.retire"])
+    for name in ("engine.lower", "engine.compile"):
+        assert all(_inside(s, spans["engine.build"]) for s in spans[name])
+    ids = ["r0", "r1", "r2"]
+    assert sorted(a["request_id"] for *_, a in spans["serve.refill"]) == ids
+    assert sorted(a["request_id"] for *_, a in spans["serve.retire"]) == ids
+    assert all(a["outcome"] == "converged" and "lane" in a
+               for *_, a in spans["serve.retire"])
+    # one build span for the call, not one per rung of auto's ladder;
+    # each refill assembles once, the resident build twice
+    assert len(spans["engine.build"]) == 1
+    assert len(spans["assembly.host"]) == 3 + 2
+
+
+def test_span_is_one_annotation_unless_a_tracer_is_active(tmp_path):
+    """With no tracer a span is the profiler annotation alone; with one,
+    each span is also a schema-valid record, its request and lane
+    first-class; after ``stop()`` nothing more reaches the sink."""
+    from poisson_ellipse_tpu.serve import Scheduler
+
+    assert obs_trace.active() is None
+    assert type(obs_trace.span("serve.refill", request_id="r0", lane=1)) \
+        is jax.profiler.TraceAnnotation
+
+    path = tmp_path / "spans.jsonl"
+    obs_trace.start(path)
+    try:
+        sched = Scheduler(lanes=2, chunk=8)
+        assert sched.submit(Problem(M=20, N=20), request_id="r0") is None
+        sched.drain()
+    finally:
+        obs_trace.stop()
+    recs = obs_trace.read_jsonl(path)
+    assert obs_trace.validate_file(path) == []
+    spans = [r for r in recs if r["kind"] == "span"]
+    assert {r["name"] for r in spans} == {*SERVE_SPANS, "assembly.host"}
+    (refill,) = [r for r in spans if r["name"] == "serve.refill"]
+    (retire,) = [r for r in spans if r["name"] == "serve.retire"]
+    assert (refill["request_id"], refill["lane"]) == ("r0", 0)
+    assert "fields" not in refill
+    assert (retire["request_id"], retire["lane"]) == ("r0", 0)
+    assert retire["fields"] == {"outcome": "converged"}
+
+    with obs_trace.span("serve.step"):
+        pass
+    assert len(obs_trace.read_jsonl(path)) == len(recs)
 
 
 # ---------------------------------------------------------- static cost
@@ -724,6 +847,24 @@ def test_harness_trace_flag_end_to_end(tmp_path, capsys):
         assert expected in names, (expected, names)
     # the CLI closed its tracer: nothing ambient leaks into later runs
     assert obs_trace.active() is None
+
+
+def test_harness_profile_is_one_event_not_spans(tmp_path, capsys):
+    """``--profile``'s per-op means come from a replay apart from the
+    run: one ``profile`` event carrying them, no span claiming a start."""
+    from poisson_ellipse_tpu.harness.__main__ import main
+
+    path = tmp_path / "profile.jsonl"
+    rc = main(["10", "10", "--mode", "single", "--profile", "--trace",
+               str(path), "--json"])
+    assert rc == 0
+    phases = json.loads(capsys.readouterr().out.strip())["phase_s"]
+    assert obs_trace.validate_file(path) == []
+    recs = obs_trace.read_jsonl(path)
+    (profile,) = [r for r in recs if r["name"] == "profile"]
+    assert profile["kind"] == "event"
+    assert profile["fields"]["phase_s"] == pytest.approx(phases)
+    assert not [r for r in recs if r["name"].startswith("profile:")]
 
 
 def test_harness_metrics_flag_writes_openmetrics_snapshot(tmp_path, capsys):
