@@ -31,6 +31,8 @@ instead of test-local jaxpr walks.
 
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 
@@ -86,6 +88,68 @@ def xla_cost(fn, args) -> dict | None:
             float(bytes_accessed) if bytes_accessed is not None else None
         ),
     }
+
+
+# -- the compiled loop body --------------------------------------------------
+
+_HLO_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%(?P<name>\S+) = (?P<type>.*?) (?P<op>[a-z][a-z0-9-]*)\("
+)
+_HLO_ARRAY = re.compile(r"^[a-z0-9]+\[(?P<dims>[0-9,]*)\]")
+_HLO_CYCLES = re.compile(r'"estimated_cycles":"(\d+)"')
+RELAYOUT_OPS = ("copy", "pad", "transpose")
+# the sharded PCG loop's name in the compiled program's op_name metadata
+SHARDED_LOOP = "shard_map/while"
+# a result this wide in every dimension is a whole block, not an edge
+WHOLE_BLOCK_EXTENT = 1000
+
+
+def loop_body_ops(hlo_text: str) -> list:
+    """The top-level instructions of the compiled ``while`` body whose
+    loop carries ``SHARDED_LOOP`` in its ``op_name`` metadata, read from
+    ``compiled.as_text()``: one dict per instruction with ``name``,
+    ``op`` (the opcode), ``dims`` (the result's extents; ``None`` for a
+    tuple) and ``cycles`` (the TPU compiler's ``estimated_cycles``, 0
+    where it gives none). Raises ``ValueError`` when no such loop is
+    compiled in."""
+    lines = hlo_text.splitlines()
+    body = None
+    for line in lines:
+        if " while(" in line and f'{SHARDED_LOOP}"' in line:
+            body = re.search(r"body=%([^\s,]+)", line).group(1)
+            break
+    if body is None:
+        raise ValueError(
+            f"no while loop named {SHARDED_LOOP!r} in the program")
+    start = next(i for i, line in enumerate(lines)
+                 if line.startswith(f"%{body} "))
+    ops = []
+    for line in lines[start + 1:]:
+        if line.strip() == "}":
+            break
+        m = _HLO_INSTR.match(line)
+        if m is None:
+            continue
+        arr = _HLO_ARRAY.match(m["type"])
+        cycles = _HLO_CYCLES.search(line)
+        ops.append({
+            "name": m["name"],
+            "op": m["op"],
+            "dims": (tuple(int(d) for d in arr["dims"].split(",") if d)
+                     if arr else None),
+            "cycles": int(cycles.group(1)) if cycles else 0,
+        })
+    return ops
+
+
+def whole_block_relayouts(ops: list) -> list:
+    """The copies, pads and transposes among ``ops`` (``loop_body_ops``)
+    whose result spans at least ``WHOLE_BLOCK_EXTENT`` in every
+    dimension: the whole-block layout changes a loop body pays for every
+    iteration."""
+    return [o for o in ops
+            if o["op"] in RELAYOUT_OPS and o["dims"]
+            and min(o["dims"]) >= WHOLE_BLOCK_EXTENT]
 
 
 # -- the per-engine report ---------------------------------------------------

@@ -41,15 +41,28 @@ def apply_a_block(w_ext, a_ext, b_ext, h1, h2):
     as ``mat_A_local`` only writes owned interior nodes
     (``stage2-mpi/poisson_mpi_decomp.cpp:194-213``).
     """
-    wc = w_ext[1:-1, 1:-1]
-    ax = -(
-        a_ext[2:, 1:-1] * (w_ext[2:, 1:-1] - wc) / h1
-        - a_ext[1:-1, 1:-1] * (wc - w_ext[:-2, 1:-1]) / h1
-    ) / h1
-    ay = -(
-        b_ext[1:-1, 2:] * (w_ext[1:-1, 2:] - wc) / h2
-        - b_ext[1:-1, 1:-1] * (wc - w_ext[1:-1, :-2]) / h2
-    ) / h2
+    return apply_a_neighbours(
+        w_ext[1:-1, 1:-1],
+        w_ext[2:, 1:-1], w_ext[:-2, 1:-1], w_ext[1:-1, 2:], w_ext[1:-1, :-2],
+        *coefficient_faces(a_ext, b_ext), h1, h2,
+    )
+
+
+def coefficient_faces(a_ext, b_ext):
+    """The four (bm, bn) coefficient faces ``apply_a_neighbours`` reads,
+    sliced from halo-extended (bm+2, bn+2) blocks: a at i+1 and i, b at
+    j+1 and j."""
+    return (a_ext[2:, 1:-1], a_ext[1:-1, 1:-1],
+            b_ext[1:-1, 2:], b_ext[1:-1, 1:-1])
+
+
+def apply_a_neighbours(wc, w_e, w_w, w_n, w_s, a_e, a_c, b_n, b_c, h1, h2):
+    """A·w from the owned values ``wc`` and their four neighbours (i+1,
+    i−1, j+1, j−1), all (bm, bn), and the coefficient faces
+    (``coefficient_faces``): ``apply_a_block``'s expression, term for
+    term, for callers that hold the neighbours in another form."""
+    ax = -(a_e * (w_e - wc) / h1 - a_c * (wc - w_w) / h1) / h1
+    ay = -(b_n * (w_n - wc) / h2 - b_c * (wc - w_s) / h2) / h2
     return ax + ay
 
 

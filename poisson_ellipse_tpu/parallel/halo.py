@@ -68,6 +68,22 @@ def halo_extend(u, px: int, py: int, width: int = 1):
     return jnp.concatenate([lo_y, u, hi_y], axis=1)
 
 
+def halo_edges(u, px: int, py: int):
+    """The four 1-cell edges ``halo_extend`` would ring a (bm, bn) block
+    with, as received: (lo_x, hi_x) are the (bn,) rows of the x
+    predecessor and successor, (lo_y, hi_y) the (bm,) columns of the y
+    predecessor and successor, zeros where there is none. No corners: a
+    5-point stencil never reads them. The same four ``ppermute``s.
+
+    Every edge travels as a vector: slicing a (bm, 1) column makes XLA
+    transpose the whole block it is cut from."""
+    lo_x = _shift_lo_to_hi(u[-1], AXIS_X, px)
+    hi_x = _shift_hi_to_lo(u[0], AXIS_X, px)
+    lo_y = _shift_lo_to_hi(u[:, -1], AXIS_Y, py)
+    hi_y = _shift_hi_to_lo(u[:, 0], AXIS_Y, py)
+    return lo_x, hi_x, lo_y, hi_y
+
+
 def halo_extend_stacked(us, px: int, py: int, width: int = 1):
     """Halo exchange for k arrays in one message round.
 

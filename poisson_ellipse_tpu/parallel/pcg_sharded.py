@@ -7,7 +7,7 @@ per PCG iteration:
 
   reference stage4 (per iteration)          here (per iteration)
   ---------------------------------------   ---------------------------------
-  4× (D2H memcpy → MPI_Sendrecv → H2D)      1 halo_extend = 4 lax.ppermute
+  4× (D2H memcpy → MPI_Sendrecv → H2D)      1 halo_edges = 4 lax.ppermute
   3× (dot kernel → D2H 256KiB partials      2 lax.psum collectives (denom;
       → host sum → MPI_Allreduce)              [zr, ‖Δw‖²] batched as one)
   α/β/convergence on host                   α/β/convergence on device in
@@ -37,8 +37,13 @@ from poisson_ellipse_tpu.obs.convergence import (
     trace_of,
 )
 from poisson_ellipse_tpu.ops import assembly
-from poisson_ellipse_tpu.ops.stencil import apply_a_block, apply_dinv, diag_d_block
-from poisson_ellipse_tpu.parallel.halo import halo_extend
+from poisson_ellipse_tpu.ops.stencil import (
+    apply_a_neighbours,
+    apply_dinv,
+    coefficient_faces,
+    diag_d_block,
+)
+from poisson_ellipse_tpu.parallel.halo import halo_edges, halo_extend
 from poisson_ellipse_tpu.parallel.mesh import (
     AXIS_X,
     AXIS_Y,
@@ -95,10 +100,33 @@ def _shard_ops(problem: Problem, px: int, py: int, bm: int, bn: int,
             )
 
     elif stencil_impl == "xla":
+        # XLA runs this loop with every block in one layout: the
+        # coefficient faces are sliced once, here, and each neighbour is
+        # the owned block shifted by a pad (fused into the stencil) with
+        # the received edge selected in. A halo-extended (bm+2, bn+2)
+        # block, or concatenated slices, instead makes XLA materialize
+        # shifted copies or transpose whole blocks between two layouts
+        # every iteration (11 at 4096² on a 2×2 v5e;
+        # ``tools/loop_copies.py``). The edge masks are constants: built
+        # from an iota they become per-iteration ops of their own.
+        faces = coefficient_faces(a_ext, b_ext)
+        zero = jnp.zeros((), dtype)
+        rows, cols = np.arange(bm)[:, None], np.arange(bn)
+
+        def shifted(u, axis: int, by: int):
+            """u[i + by] along ``axis``, zero past the block's edge."""
+            pads = [(0, 0, 0), (0, 0, 0)]
+            pads[axis] = (-by, by, 0)
+            return lax.pad(u, zero, pads)
 
         def stencil(p):
-            p_ext = halo_extend(p, px, py)
-            return apply_a_block(p_ext, a_ext, b_ext, h1, h2) * maskd
+            lo_x, hi_x, lo_y, hi_y = halo_edges(p, px, py)
+            p_e = jnp.where(rows == bm - 1, hi_x, shifted(p, 0, 1))
+            p_w = jnp.where(rows == 0, lo_x, shifted(p, 0, -1))
+            p_n = jnp.where(cols == bn - 1, hi_y[:, None], shifted(p, 1, 1))
+            p_s = jnp.where(cols == 0, lo_y[:, None], shifted(p, 1, -1))
+            return apply_a_neighbours(
+                p, p_e, p_w, p_n, p_s, *faces, h1, h2) * maskd
 
     else:
         raise ValueError(f"unknown stencil_impl: {stencil_impl!r}")

@@ -73,6 +73,21 @@ def test_engine_compiles_a_mosaic_kernel_for_v5e(one_chip, engine, grid):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("grid", [2048, 4096])
+def test_sharded_loop_keeps_blocks_in_their_layout(one_chip, topo, grid):
+    """The 2x2 sharded PCG loop body holds no whole-block copy, pad or
+    transpose: every block stays in the carry's layout, iteration after
+    iteration (the loop re-laid 11 blocks out per iteration at both
+    grids when its stencil read a halo-extended block)."""
+    from poisson_ellipse_tpu.obs.static_cost import whole_block_relayouts
+    from poisson_ellipse_tpu.parallel.mesh import make_mesh
+    from tools.loop_copies import compiled_body
+
+    ops = compiled_body(Problem(M=grid, N=grid), make_mesh(topo.devices))
+    assert any(o["op"] == "collective-permute-start" for o in ops)
+    assert whole_block_relayouts(ops) == []
+
+
 @pytest.mark.parametrize("engine,grid", [
     ("resident", (40, 60)),
     ("streamed", (400, 600)),

@@ -802,6 +802,44 @@ def test_collectives_table_shape():
     assert t["engines"]["pipelined"]["psum_per_iter"] == 1
 
 
+_LOOP_HLO = """\
+%body.3 (param.0: (s32[], f32[2049,2049])) -> (s32[], f32[2049,2049]) {
+  %param.0 = (s32[]{:T(128)}, f32[2049,2049]{1,0:T(8,128)}) parameter(0)
+  %fusion.5 = (f32[1,2049]{1,0:T(1,128)}, f32[1,2049]{1,0:T(1,128)}) fusion(%gte.1), kind=kLoop, calls=%fc.5, backend_config={"window_config":{"estimated_cycles":"2273"}}
+  %copy.84 = f32[2049,2049]{0,1:T(8,128)S(1)} copy(%gte.1), backend_config={"window_config":{"estimated_cycles":"137785"}}
+  %copy.86 = f32[1,2049]{0,1:T(8,128)} copy(%cp.1), backend_config={"window_config":{"estimated_cycles":"100"}}
+  ROOT %tuple.9 = (s32[]{:T(128)}, f32[2049,2049]{1,0:T(8,128)}) tuple(%add.1, %copy.84)
+}
+ENTRY %main (p: f32[2049,2049]) -> f32[2049,2049] {
+  %while.26 = (s32[]{:T(128)}, f32[2049,2049]{1,0:T(8,128)}) while(%tuple.1), condition=%cond.2, body=%body.3, metadata={op_name="jit(solver)/shard_map/while" stack_frame_id=58}
+}
+"""
+
+
+def test_loop_body_ops_reads_the_compiled_loop():
+    from poisson_ellipse_tpu.obs.static_cost import (
+        loop_body_ops,
+        whole_block_relayouts,
+    )
+    from poisson_ellipse_tpu.parallel.mesh import make_mesh
+    from poisson_ellipse_tpu.parallel.pcg_sharded import build_sharded_solver
+
+    ops = loop_body_ops(_LOOP_HLO)
+    assert [(o["op"], o["dims"], o["cycles"]) for o in ops] == [
+        ("parameter", None, 0), ("fusion", None, 2273),
+        ("copy", (2049, 2049), 137785), ("copy", (1, 2049), 100),
+        ("tuple", None, 0),
+    ]
+    assert [o["name"] for o in whole_block_relayouts(ops)] == ["copy.84"]
+    with pytest.raises(ValueError, match="no while loop"):
+        loop_body_ops(_LOOP_HLO.replace("shard_map/while", "pjit/while"))
+    # the same reader on a program this backend compiled
+    solver, args = build_sharded_solver(
+        Problem(M=12, N=12), make_mesh(jax.devices()[:4]), jnp.float32)
+    real = loop_body_ops(solver.lower(*args).compile().as_text())
+    assert any(o["op"] == "all-reduce" for o in real)
+
+
 def test_multichip_table_carries_collectives():
     from poisson_ellipse_tpu.harness.bench_multichip import scaling_table
 

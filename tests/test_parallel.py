@@ -82,6 +82,52 @@ def test_halo_extend_reconstructs_neighbors():
     assert blk11[0, 0] == g_np[3, 2]
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
+@pytest.mark.parametrize("grid", [(40, 60), (41, 67)])
+def test_shard_stencil_is_bitwise_the_halo_extended_stencil(grid, dtype):
+    """The sharded loop's stencil (owned block + received edges, no
+    halo-extended block) equals ``apply_a_block`` over ``halo_extend``
+    bit for bit, on a 2x2 mesh whose shards carry padding (40x60: 21x31
+    blocks; 41x67: 21x34), and ``halo_edges`` is ``halo_extend``'s ring."""
+    from poisson_ellipse_tpu.ops import assembly
+    from poisson_ellipse_tpu.ops.stencil import apply_a_block
+    from poisson_ellipse_tpu.parallel.halo import halo_edges
+    from poisson_ellipse_tpu.parallel.pcg_sharded import _pad_to, _shard_ops
+
+    problem = Problem(M=grid[0], N=grid[1])
+    mesh = mesh_of(4)
+    g1p, g2p = padded_dims(problem.node_shape, mesh)
+    bm, bn = g1p // 2, g2p // 2
+    a, b, _rhs = assembly.assemble_numpy(problem)
+    np_dtype = np.dtype(dtype)
+    a, b = (_pad_to(x, g1p, g2p).astype(np_dtype) for x in (a, b))
+    p = np.random.default_rng(7).standard_normal((g1p, g2p)).astype(np_dtype)
+    spec = jax.sharding.PartitionSpec("x", "y")
+
+    def f(a_blk, b_blk, p_blk):
+        a_ext = halo_extend(a_blk, 2, 2)
+        b_ext = halo_extend(b_blk, 2, 2)
+        stencil, _pdot, _d, maskd = _shard_ops(
+            problem, 2, 2, bm, bn, a_ext, b_ext, dtype)
+        h1 = jnp.asarray(problem.h1, dtype)
+        h2 = jnp.asarray(problem.h2, dtype)
+        p_ext = halo_extend(p_blk, 2, 2)
+        ref = apply_a_block(p_ext, a_ext, b_ext, h1, h2) * maskd
+        lo_x, hi_x, lo_y, hi_y = halo_edges(p_blk, 2, 2)
+        ring = (jnp.all(lo_x == p_ext[0, 1:-1])
+                & jnp.all(hi_x == p_ext[-1, 1:-1])
+                & jnp.all(lo_y == p_ext[1:-1, 0])
+                & jnp.all(hi_y == p_ext[1:-1, -1]))
+        return stencil(p_blk), ref, ring[None, None]
+
+    got, ref, ring = jax.jit(shard_map(
+        f, mesh=mesh, in_specs=(spec,) * 3, out_specs=(spec,) * 3,
+    ))(a, b, p)
+    assert got.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    assert np.asarray(ring).all()
+
+
 @pytest.mark.parametrize("n_devices", [1, 2, 4, 8])
 def test_sharded_matches_single_chip(n_devices):
     problem = Problem(M=40, N=40)
