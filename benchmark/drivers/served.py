@@ -1,7 +1,10 @@
 """An open loop of independent users in front of ``serve.Scheduler``.
 
 The scheduler is built with the program's defaults (lanes, chunk, queue
-capacity, ``keep_solutions``). Set-up pre-warms the configuration's
+capacity, ``keep_solutions``) on one chip. On more, one scheduler drives
+them all: ``Scheduler(mesh=...)`` over a mesh of the cell's chips, with
+the default lanes on every chip, whole lanes sharded over the mesh.
+Set-up pre-warms the configuration's
 bucket and serves a few requests through it, so the chunk advance, the
 refill and the retirement are compiled before the window.
 
@@ -23,26 +26,40 @@ import numpy as np
 
 from benchmark import traffic
 
+WARM = "warm-"
+
 
 def warm(ctx, sched) -> None:
     """Compile every program the window drives: a full bucket with a
     queue behind it, so lanes retire and refill. Bounded by the mix's
-    ``drain_s``: a scheduler that never finishes shows in the window."""
+    ``drain_s``: a scheduler that never finishes shows in the window,
+    which leaves out what warm-up requests still return there."""
     sched.prewarm(ctx.problem(None))
     choices = ctx.config["eps_choices"]
     n = 2 * sched.lanes + 1
     for i in range(n):
-        sched.submit(ctx.problem(choices[i % len(choices)]))
+        sched.submit(ctx.problem(choices[i % len(choices)]),
+                     request_id=WARM + str(i))
     done, t_end = 0, time.perf_counter() + float(ctx.mix["drain_s"])
     while done < n and time.perf_counter() < t_end:
         sched.step()
         done += len(sched.collect())
 
 
-def run(ctx) -> dict:
+def build(ctx):
     from poisson_ellipse_tpu.serve import Scheduler
 
-    sched = Scheduler()
+    if len(ctx.devices) == 1:
+        return Scheduler()
+    from poisson_ellipse_tpu.parallel.mesh import make_mesh
+    from poisson_ellipse_tpu.serve.scheduler import DEFAULT_LANES
+
+    return Scheduler(lanes=DEFAULT_LANES * len(ctx.devices),
+                     mesh=make_mesh(ctx.devices))
+
+
+def run(ctx) -> dict:
+    sched = build(ctx)
     warm(ctx, sched)
     arrivals = traffic.schedule(ctx.config, ctx.mix, ctx.seed, ctx.seconds)
     results, lag = {}, []
@@ -51,7 +68,8 @@ def run(ctx) -> dict:
     def collect(now):
         with ctx.span("collect"):
             for rid, res in sched.collect().items():
-                results[int(rid)] = (res, now)
+                if not rid.startswith(WARM):
+                    results[int(rid)] = (res, now)
 
     ctx.open_window()
     t0 = time.perf_counter()

@@ -21,7 +21,8 @@ import pytest  # noqa: E402
 
 # test-sized grids for the configurations, and a quick drain
 SMALL_GRIDS = {"ellipse-4096": [64, 64], "ellipse-4096-2x2": [64, 64],
-               "ellipse-400x600": [40, 60]}
+               "ellipse-400x600": [40, 60], "ellipse-400x600-2x2": [40, 60]}
+SERVED_MIXES = ("served-poisson", "served-poisson-2x2")
 
 
 def make_small_bench(root):
@@ -33,12 +34,41 @@ def make_small_bench(root):
                     os.path.join(root, "benchmark"),
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
+    add_four_chip_served_cell(root)
     for name, grid in SMALL_GRIDS.items():
         edit_json(os.path.join(root, "benchmark", "configs", name + ".json"),
                   grid=grid)
-    edit_json(os.path.join(root, "benchmark", "traffic",
-                           "served-poisson.json"), rate_per_s=20, drain_s=2)
+    for mix in SERVED_MIXES:
+        # a drain long enough for the four-chip warm-up on a busy CPU
+        edit_json(os.path.join(root, "benchmark", "traffic", mix + ".json"),
+                  rate_per_s=20, drain_s=10)
     return harness.Bench(root=root, bench_dir=os.path.join(root, "benchmark"))
+
+
+def add_four_chip_served_cell(root):
+    """serve-400x600-4chip, the 400x600 stream through one scheduler whose
+    lanes are sharded over four chips, as files and entries in the copy
+    under ``root``: the served driver's multi-chip path. The benchmark
+    itself does not hold the cell yet (its knee and spreads are not
+    measured on the chip)."""
+    with open(os.path.join(os.path.dirname(__file__), "data",
+                           "serve_4chip_cell.json")) as fh:
+        cell = json.load(fh)
+    bench_dir = os.path.join(root, "benchmark")
+    for kind, name in (("configs", cell["config_entry"]["name"]),
+                       ("traffic", cell["cell"]["traffic"])):
+        with open(os.path.join(bench_dir, kind, name + ".json"), "w") as fh:
+            json.dump(cell["config" if kind == "configs" else "mix"], fh)
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path) as fh:
+        spec = json.load(fh)
+    spec["configs"].append(cell["config_entry"])
+    spec["workloads"].append(cell["cell"])
+    for m in spec["end_to_end"]:
+        if m["name"] in ("latency_p50_s", "solves_per_s"):
+            m["workloads"].append(cell["cell"]["name"])
+    with open(path, "w") as fh:
+        json.dump(spec, fh)
 
 
 def edit_json(path, **changes):

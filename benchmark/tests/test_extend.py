@@ -68,8 +68,10 @@ def test_new_files_and_entries_only(small_bench, tmp_path):
 def test_split_metrics_share_a_reader(small_bench):
     """``<quantity>.<split>`` falls back to ``<quantity>.py``; a file with
     the whole name wins."""
-    shared = small_bench.reader("device_idle_share.solve")
-    assert os.path.basename(shared.__file__) == "device_idle_share.py"
-    assert small_bench.reader("device_idle_share.serve").read is not None
+    for metric in ("device_idle_share.solve", "device_idle_share.serve",
+                   "collective_share.solve", "collective_share.serve"):
+        shared = small_bench.reader(metric)
+        assert os.path.basename(shared.__file__) == \
+            metric.split(".")[0] + ".py"
     own = small_bench.reader("build_s.request")
     assert os.path.basename(own.__file__) == "build_s.request.py"

@@ -15,7 +15,7 @@ from conftest import edit_json, run
 
 def test_sound_runs_are_correct(small_bench):
     for cell in ("solve-4096", "direct-400x600", "serve-400x600",
-                 "solve-4096-2x2"):
+                 "solve-4096-2x2", "serve-400x600-4chip"):
         out = run(small_bench, cell)
         assert out["correct"] is True, (cell, out["checks"])
 
@@ -23,7 +23,7 @@ def test_sound_runs_are_correct(small_bench):
 # -- the control ------------------------------------------------------------
 
 @pytest.mark.parametrize("config", ["ellipse-4096", "ellipse-4096-2x2",
-                                    "ellipse-400x600"])
+                                    "ellipse-400x600", "ellipse-400x600-2x2"])
 def test_control_fails(small_bench, config):
     cfg = small_bench.config(config)
     device = jax.devices()[0]
@@ -120,17 +120,17 @@ def test_sharded_answer_altered(small_bench, monkeypatch):
     assert out["correct"] is False, out["checks"]
 
 
-# -- the served cell --------------------------------------------------------
+# -- the served cells -------------------------------------------------------
+# serve-400x600 advances its lanes through ``scheduler._bucket_advance``;
+# serve-400x600-4chip through ``batched_sharded.build_sharded_chunk_advance``
+# (16 lanes, 4 on each of the 2x2 mesh's chips)
 
-def broken_advance(keep_lanes):
-    """The bucket's chunk advance, with the lanes ``keep_lanes`` picks
-    left as they were."""
-    from poisson_ellipse_tpu.serve import scheduler
+def broken_advance(real, keep_lanes):
+    """The bucket's chunk advance that ``real`` builds, with the lanes
+    ``keep_lanes`` picks left as they were."""
 
-    real = scheduler._bucket_advance
-
-    def advance(*key):
-        fn, proto = real(*key)
+    def advance(*a, **kw):
+        fn, proto = real(*a, **kw)
 
         def step(a3, b3, mask, h1, h2, delta, state, limit):
             new = fn(a3, b3, mask, h1, h2, delta, state, limit)
@@ -144,23 +144,87 @@ def broken_advance(keep_lanes):
     return advance
 
 
+def patch_advance(monkeypatch, cell, keep_lanes):
+    from poisson_ellipse_tpu.parallel import batched_sharded
+    from poisson_ellipse_tpu.serve import scheduler
+
+    module, name = ((batched_sharded, "build_sharded_chunk_advance")
+                    if cell == "serve-400x600-4chip"
+                    else (scheduler, "_bucket_advance"))
+    monkeypatch.setattr(module, name,
+                        broken_advance(getattr(module, name), keep_lanes))
+
+
+SERVED_CELLS = {"serve-400x600": "served-poisson",
+                "serve-400x600-4chip": "served-poisson-2x2"}
+
+
+@pytest.mark.parametrize("cell", sorted(SERVED_CELLS))
 @pytest.mark.parametrize("keep_lanes", [
     lambda lanes: np.ones(lanes, bool),                  # state unchanged
     lambda lanes: np.arange(lanes) >= lanes // 2,        # half the batch
 ], ids=["unchanged", "half-batch"])
-def test_served_step_faults(small_bench, monkeypatch, keep_lanes):
-    from poisson_ellipse_tpu.serve import scheduler
-
+def test_served_step_faults(small_bench, monkeypatch, keep_lanes, cell):
     # load enough that every lane takes requests
-    edit_json(os.path.join(small_bench.dir, "traffic", "served-poisson.json"),
-              rate_per_s=400)
-    monkeypatch.setattr(scheduler, "_bucket_advance",
-                        broken_advance(keep_lanes))
-    out = run(small_bench, "serve-400x600")
+    edit_json(os.path.join(small_bench.dir, "traffic",
+                           SERVED_CELLS[cell] + ".json"), rate_per_s=400)
+    patch_advance(monkeypatch, cell, keep_lanes)
+    out = run(small_bench, cell)
     assert out["correct"] is False, out["checks"]
 
 
-def test_served_answer_altered(small_bench, monkeypatch):
+def test_four_chip_served_one_chips_lanes_unchanged(small_bench, monkeypatch):
+    """The lanes of the mesh's first chip (lanes 0-3 of 16) return their
+    state unchanged; the other chips' lanes answer."""
+    cell = "serve-400x600-4chip"
+    edit_json(os.path.join(small_bench.dir, "traffic",
+                           SERVED_CELLS[cell] + ".json"), rate_per_s=400)
+    patch_advance(monkeypatch, cell, lambda lanes: np.arange(lanes) < 4)
+    out = run(small_bench, cell)
+    assert out["correct"] is False, out["checks"]
+
+
+def built_schedulers(monkeypatch):
+    """Every ``Scheduler`` built from here on, with its arguments."""
+    from poisson_ellipse_tpu.serve import scheduler
+
+    built = []
+    real = scheduler.Scheduler.__init__
+
+    def init(self, *a, **kw):
+        built.append((self, a, kw))
+        real(self, *a, **kw)
+    monkeypatch.setattr(scheduler.Scheduler, "__init__", init)
+    return built
+
+
+def test_four_chip_served_run_spans_the_four_devices(small_bench,
+                                                     monkeypatch):
+    built = built_schedulers(monkeypatch)
+    out = run(small_bench, "serve-400x600-4chip")
+    assert out["correct"] is True, out["checks"]
+    ((sched, _, _),) = built
+    assert sched.lanes == 16
+    assert sched.mesh.devices.shape == (2, 2)
+    assert set(sched.mesh.devices.flat) == set(jax.devices()[:4])
+    (ctx,) = sched._ctxs.values()
+    for lanes in ctx.state[1:]:
+        assert lanes.shape[0] == 16
+        assert lanes.sharding.device_set == set(jax.devices()[:4])
+
+
+def test_one_chip_served_run_builds_the_default_scheduler(small_bench,
+                                                         monkeypatch):
+    built = built_schedulers(monkeypatch)
+    out = run(small_bench, "serve-400x600")
+    assert out["correct"] is True, out["checks"]
+    ((sched, args, kwargs),) = built
+    assert (args, kwargs) == ((), {})
+    assert sched.mesh is None and sched.lanes == 4
+
+
+@pytest.mark.parametrize("cell", sorted(SERVED_CELLS))
+def test_served_answer_altered(small_bench, monkeypatch, cell):
     from poisson_ellipse_tpu.serve import scheduler
 
     real = scheduler.Scheduler.collect
@@ -172,5 +236,5 @@ def test_served_answer_altered(small_bench, monkeypatch):
                 res.w = res.w * 1.01
         return out
     monkeypatch.setattr(scheduler.Scheduler, "collect", collect)
-    out = run(small_bench, "serve-400x600")
+    out = run(small_bench, cell)
     assert out["correct"] is False, out["checks"]

@@ -143,3 +143,114 @@ def test_layout_copy_share_counts_plain_copies_only():
     assert reader.read(view) == pytest.approx(4.0)
     view.trace = tracing.reduce(synthetic())
     assert reader.read(view) is None
+
+
+def reader(metric):
+    from benchmark import harness
+
+    return harness.Bench().reader(metric)
+
+
+def serve_events():
+    """Two ``serve.step`` spans of the program inside the benchmark's
+    ``scheduler.step``: refills, advances, boundaries holding
+    retirements."""
+    ev = synthetic()
+    ev["program"] = [["serve.step", 0, 20 * MS],
+                     ["serve.refill", 0, 6 * MS],
+                     ["serve.advance", 6 * MS, 2 * MS],
+                     ["serve.boundary", 8 * MS, 10 * MS],
+                     ["serve.retire", 12 * MS, 4 * MS],
+                     ["serve.step", 22 * MS, 20 * MS],
+                     ["serve.advance", 22 * MS, 3 * MS],
+                     ["serve.boundary", 25 * MS, 15 * MS],
+                     ["serve.retire", 30 * MS, 2 * MS],
+                     ["serve.retire", 33 * MS, 2 * MS]]
+    return ev
+
+
+def test_serve_span_readers():
+    view = type("View", (), {"trace": tracing.reduce(serve_events())})
+    got = {m: reader(m).read(view) for m in (
+        "refill_ms.serve", "advance_ms.serve", "boundary_ms.serve",
+        "retire_ms.serve")}
+    # per serve.step (2): refill 6 ms, advance 5, boundary 25 less
+    # retire 8, retire 8
+    assert got == pytest.approx({"refill_ms.serve": 3.0,
+                                 "advance_ms.serve": 2.5,
+                                 "boundary_ms.serve": 8.5,
+                                 "retire_ms.serve": 4.0})
+    # the benchmark's own span still feeds step_ms.serve
+    assert reader("step_ms.serve").read(view) == pytest.approx(45.0)
+
+
+def test_serve_span_readers_find_nothing_without_program_spans():
+    view = type("View", (), {"trace": tracing.reduce(synthetic())})
+    for m in ("refill_ms.serve", "advance_ms.serve", "boundary_ms.serve",
+              "retire_ms.serve"):
+        assert reader(m).read(view) is None
+    view.trace = None
+    assert reader("refill_ms.serve").read(view) is None
+
+
+def test_program_spans_name_gaps_and_are_totalled():
+    out = tracing.reduce(serve_events(), ["/device:TPU:0"])
+    gaps = dict(out["idle_gaps"])
+    # device gaps 0-10, 30-50 and 60-100 ms, each named by the innermost
+    # span at its midpoint: 5 ms lies in serve.refill (not in the
+    # benchmark's scheduler.step around it), 40 ms in the second
+    # serve.step (22-42), 80 ms in sleep
+    assert gaps == pytest.approx({"serve.refill": 0.010,
+                                  "serve.step": 0.020, "sleep": 0.040})
+    assert out["spans"]["serve.step"] == [2, pytest.approx(0.040)]
+    assert out["spans"]["scheduler.step"] == [1, pytest.approx(0.045)]
+
+
+def test_collectives_by_opcode():
+    assert tracing.is_collective(
+        "%psum_invariant.38 = f32[] all-reduce(f32[] %x), channel_id=1")
+    assert tracing.is_collective("collective-permute-start.3")
+    assert tracing.is_collective(
+        "%cp.1 = (f32[4], f32[4]) collective-permute-start(f32[4] %a)")
+    assert not tracing.is_collective(
+        "%fusion.2 = f32[] fusion(f32[] %all-reduce.3), kind=kLoop")
+    assert not tracing.is_collective("psum_invariant.38")
+    ev = synthetic()
+    ev["devices"]["/device:TPU:1"].append(["psum_invariant.38", 60 * MS,
+                                           10 * MS])
+    plain = tracing.reduce(ev)
+    ev["collectives"] = ["psum_invariant.38"]
+    named = tracing.reduce(ev)
+    # 10 ms more of collectives on one device of two
+    assert named["collective_s"] - plain["collective_s"] == \
+        pytest.approx(0.005)
+
+
+def test_collective_share_reads_both_splits_alike():
+    view = type("View", (), {"trace": tracing.reduce(synthetic())})
+    # 25 ms of the 100 ms window
+    assert reader("collective_share.solve").read(view) == pytest.approx(25.0)
+    assert reader("collective_share.serve").read(view) == pytest.approx(25.0)
+
+
+def test_program_spans_from_a_cpu_trace(tmp_path):
+    """The program's spans come back under ``program``, stripped of its
+    prefix; ``host`` keeps the benchmark's alone."""
+    import jax
+
+    from poisson_ellipse_tpu.obs import trace as obs_trace
+
+    tracing.start(str(tmp_path))
+    with tracing.span(tracing.WINDOW_SPAN):
+        with tracing.span("scheduler.step"):
+            with obs_trace.span("serve.step"):
+                with obs_trace.span("serve.boundary"):
+                    time.sleep(0.002)
+    jax.profiler.stop_trace()
+    ev = tracing.events_from_dir(str(tmp_path))
+    assert sorted(n for n, _, _ in ev["host"]) == ["scheduler.step",
+                                                    "window"]
+    assert sorted(n for n, _, _ in ev["program"]) == ["serve.boundary",
+                                                       "serve.step"]
+    spans = tracing.reduce({**ev, "devices": {"/device:CPU:0": []}})["spans"]
+    assert spans["serve.step"][0] == spans["serve.boundary"][0] == 1

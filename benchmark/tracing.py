@@ -9,7 +9,12 @@ small recorded trace without a chip:
 ``devices`` holds one list per device plane (the TPU's "XLA Ops" line);
 ``host`` the benchmark's own spans (``jax.profiler.TraceAnnotation``
 named ``bench:<span>``), which share the profiler's clock with the
-device planes.
+device planes. A trace read from a file also has ``program``: the
+program's own spans (``obs.trace.span``, named
+``obs.trace.PROFILER_PREFIX + <span>``), in the same form, which
+``reduce`` reads as it reads ``host``, so an idle gap is named by the
+innermost span of either kind; and ``collectives``: the ops whose HLO
+opcode is a collective, whatever their names.
 """
 
 from __future__ import annotations
@@ -30,6 +35,12 @@ WINDOW_SPAN = "window"
 OPS_LINE = "XLA Ops"
 COLLECTIVE = re.compile(
     r"all-reduce|collective-permute|all-gather|reduce-scatter|all-to-all")
+# a collective's opcode in an HLO line, where its name need not say it
+# (a psum's all-reduce is named ``psum_invariant.N``): a word followed by
+# "(", and not an operand's ``%name``
+COLLECTIVE_OPCODE = re.compile(
+    r"(?<![%\w.-])(?:all-reduce|collective-permute|all-gather"
+    r"|reduce-scatter|all-to-all)(?:-start|-done)?\(")
 TOP = 10
 
 
@@ -59,29 +70,52 @@ def op_name(text: str) -> str:
     return text
 
 
+def is_collective(text: str) -> bool:
+    """Whether an op's trace text is a collective: by its name, or, for
+    a whole HLO line, by its opcode."""
+    if COLLECTIVE.search(op_name(text)):
+        return True
+    line = text.split(" = ", 1)
+    return (text.startswith("%") and len(line) == 2
+            and COLLECTIVE_OPCODE.search(line[1]) is not None)
+
+
 def events_from_dir(trace_dir: str) -> dict:
     """Read the newest ``.xplane.pb`` under ``trace_dir`` into event lists."""
     from jax.profiler import ProfileData
+
+    from poisson_ellipse_tpu.obs.trace import PROFILER_PREFIX
 
     paths = sorted(glob.glob(os.path.join(
         trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
     if not paths:
         raise FileNotFoundError(f"no profiler trace under {trace_dir}")
     data = ProfileData.from_file(paths[-1])
-    devices, host = {}, []
+    devices, collectives = {}, set()
+    spans = {SPAN_PREFIX: [], PROFILER_PREFIX: []}
     for plane in data.planes:
         if (plane.name.startswith(DEVICE_PREFIX)
                 and not plane.name.startswith(NOT_A_DEVICE)):
-            ops = [[op_name(e.name), float(e.start_ns), float(e.duration_ns)]
-                   for line in plane.lines if line.name == OPS_LINE
-                   for e in line.events]
-            devices[plane.name] = ops
+            ops = devices[plane.name] = []
+            for line in plane.lines:
+                if line.name != OPS_LINE:
+                    continue
+                for e in line.events:
+                    ops.append([op_name(e.name), float(e.start_ns),
+                                float(e.duration_ns)])
+                    if is_collective(e.name):
+                        collectives.add(ops[-1][0])
         elif plane.name.startswith("/host:"):
-            host += [[e.name[len(SPAN_PREFIX):], float(e.start_ns),
-                      float(e.duration_ns)]
-                     for line in plane.lines for e in line.events
-                     if e.name.startswith(SPAN_PREFIX)]
-    return {"devices": devices, "host": host}
+            for line in plane.lines:
+                for e in line.events:
+                    for prefix, out in spans.items():
+                        if e.name.startswith(prefix):
+                            out.append([e.name[len(prefix):],
+                                        float(e.start_ns),
+                                        float(e.duration_ns)])
+    return {"devices": devices, "host": spans[SPAN_PREFIX],
+            "program": spans[PROFILER_PREFIX],
+            "collectives": sorted(collectives)}
 
 
 def _merged(starts, ends):
@@ -128,8 +162,8 @@ class _Spans:
 
 def reduce(events: dict, devices: list | None = None) -> dict:
     """Busy time, idle share, collective time, each op's time, the top
-    ops and the idle gaps named by what the host was doing, over the
-    ``window`` host span.
+    ops, the idle gaps named by what the host was doing and each host
+    span's totals, over the ``window`` host span.
 
     ``devices`` names the planes of the devices the cell used (default:
     every device plane). Per-device numbers are averaged over them.
@@ -147,7 +181,8 @@ def reduce(events: dict, devices: list | None = None) -> dict:
     busy, coll = [], []
     op_time = collections.Counter()
     gap_time = collections.Counter()
-    spans = events["host"]
+    spans = events["host"] + events.get("program", [])
+    collectives = set(events.get("collectives", ()))
     search = _Spans(spans)
     for plane in planes:
         ops = events["devices"].get(plane, [])
@@ -164,7 +199,8 @@ def reduce(events: dict, devices: list | None = None) -> dict:
             if t > 0:
                 op_time[str(name)] += t / len(planes)
         coll.append(sum(t for name, t in zip(names, per_name)
-                        if COLLECTIVE.search(str(name))))
+                        if str(name) in collectives
+                        or COLLECTIVE.search(str(name))))
         ms, me = _merged(cs[inside], ce[inside])
         busy.append(float(np.sum(me - ms)))
         gs = np.concatenate([[lo], me])
@@ -197,3 +233,13 @@ def _span_totals(spans, lo, hi) -> dict:
             out[name][0] += 1
             out[name][1] += d * 1e-9
     return dict(out)
+
+
+def ms_per(spans: dict, name: str, per: str, less: str | None = None):
+    """Milliseconds of the spans ``name`` (less the time of the spans
+    ``less`` inside them) per span ``per``, from ``reduce``'s span
+    totals; None where the window holds no ``name`` or no ``per``."""
+    if name not in spans or not spans.get(per, [0])[0]:
+        return None
+    total = spans[name][1] - spans.get(less, [0, 0.0])[1]
+    return 1000.0 * total / spans[per][0]
