@@ -142,6 +142,22 @@ def loop_body_ops(hlo_text: str) -> list:
     return ops
 
 
+COMPILED_COLLECTIVES = ("all-reduce", "all-gather", "all-to-all",
+                       "collective-permute", "reduce-scatter")
+
+
+def compiled_collectives(hlo_text: str) -> dict:
+    """{opcode: count} of the collectives in a compiled program's text
+    (``compiled.as_text()``): the ones the partitioner put in, which no
+    jaxpr shows. An asynchronous pair counts once, by its ``-start``."""
+    counts = dict.fromkeys(COMPILED_COLLECTIVES, 0)
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTR.match(line)
+        if m is not None and m["op"].removesuffix("-start") in counts:
+            counts[m["op"].removesuffix("-start")] += 1
+    return counts
+
+
 def whole_block_relayouts(ops: list) -> list:
     """The copies, pads and transposes among ``ops`` (``loop_body_ops``)
     whose result spans at least ``WHOLE_BLOCK_EXTENT`` in every

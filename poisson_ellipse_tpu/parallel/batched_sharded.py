@@ -31,6 +31,8 @@ natural binning boundary).
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 from jax import lax, shard_map
@@ -52,6 +54,36 @@ from poisson_ellipse_tpu.parallel.mesh import (
 )
 
 MESH_AXES = (AXIS_X, AXIS_Y)
+
+
+class LaneLayout(NamedTuple):
+    """Where the serve scheduler's per-lane arrays live on a mesh: each
+    chip holds its own lanes' slices and nothing else."""
+
+    stack: NamedSharding  # (lanes, M+1, N+1): a3, b3, mask, w, r, p
+    vector: NamedSharding  # (lanes,): h1, h2, delta and the lane flags
+    replicated: NamedSharding  # the carry's clock k, the chunk's limit
+
+    @property
+    def operands(self) -> tuple:
+        """The shardings of (a3, b3, mask, h1, h2, delta)."""
+        return (self.stack,) * 3 + (self.vector,) * 3
+
+    @property
+    def state(self) -> tuple:
+        """The shardings of the classical batched carry (k, w, r, p, zr,
+        diff, conv, bd, quar, iters)."""
+        return ((self.replicated,) + (self.stack,) * 3
+                + (self.vector,) * 6)
+
+
+def lane_layout(mesh: Mesh) -> LaneLayout:
+    """Whole lanes sharded over every device of ``mesh``."""
+    return LaneLayout(
+        NamedSharding(mesh, P(MESH_AXES, None, None)),
+        NamedSharding(mesh, P(MESH_AXES)),
+        NamedSharding(mesh, P()),
+    )
 
 
 def build_batched_sharded_solver(
@@ -209,8 +241,9 @@ def build_sharded_chunk_advance(
 
     Signature of the returned fn (matches the scheduler's single-device
     bucket advance): ``fn(a3, b3, mask, h1, h2, delta, state, limit)``
-    where ``state`` is the classical batched carry and every per-lane
-    array is sharded on its lane axis.
+    where ``state`` is the classical batched carry. Its arguments and
+    results are pinned to ``lane_layout(mesh)``: a per-lane array placed
+    anywhere else is refused, not copied onto its shards every chunk.
     """
     if mesh is None:
         mesh = make_mesh()
@@ -226,8 +259,6 @@ def build_sharded_chunk_advance(
         M=bucket[0], N=bucket[1], norm=norm, max_iter=iter_ceiling
     )
     weighted = norm == "weighted"
-    lane3 = P(MESH_AXES, None, None)
-    lane1 = P(MESH_AXES)
 
     def shard_fn(a3, b3, mask, h1, h2, delta, state, limit):
         d = diag_d_batched(a3, b3, h1, h2, mask)
@@ -255,24 +286,22 @@ def build_sharded_chunk_advance(
         out, _ = lax.while_loop(cond, body, (state, active_count(state)))
         return out
 
-    state_specs = (
-        P(),                           # k — replicated global clock
-        lane3, lane3, lane3,           # w, r, p
-        lane1, lane1,                  # zr, diff
-        lane1, lane1, lane1, lane1,    # conv, bd, quar, iters
-    )
+    layout = lane_layout(mesh)
+    state_specs = tuple(s.spec for s in layout.state)
     mapped = shard_map(
         shard_fn,
         mesh=mesh,
-        in_specs=(
-            lane3, lane3, lane3, lane1, lane1, lane1, state_specs, P()
-        ),
+        in_specs=tuple(s.spec for s in layout.operands) + (state_specs, P()),
         out_specs=state_specs,
     )
 
     # no donation: the carry is re-read at every chunk boundary for the
     # scheduler's retire/refill host work
-    return jax.jit(mapped), proto
+    return jax.jit(
+        mapped,
+        in_shardings=layout.operands + (layout.state, layout.replicated),
+        out_shardings=layout.state,
+    ), proto
 
 
 def solve_batched_sharded(

@@ -53,6 +53,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax, shard_map
 
 from poisson_ellipse_tpu.batch import batched_pcg
 from poisson_ellipse_tpu.models.problem import Problem
@@ -105,29 +106,110 @@ def _bucket_advance(Mb: int, Nb: int, dtype_name: str, norm: str):
     return jax.jit(fn), proto  # tpulint: disable=TPU004
 
 
-# no donation, matching _bucket_advance: the host re-reads the carry
-# at every boundary, and CPU/CI backends would only warn
-@jax.jit
-# tpulint: disable=TPU004
-def _refill_scatter(a3, b3, mask, h1, h2, delta, state, unit,
-                    a_p, b_p, m_p, h1v, h2v, dv, lane):
+def _set_lane(stack, lane, value):
+    return stack.at[lane].set(value)
+
+
+def _scatter_lane(a3, b3, mask, h1, h2, delta, state, unit,
+                  a_p, b_p, m_p, h1v, h2v, dv, lane, put=_set_lane):
     """One dispatch per refill: every operand slice and carry field of
     the lane scattered together. The serving target regime is
     dispatch-bound TPUs, where fifteen per-refill ``.at[].set`` round
     trips would eat the continuous-batching win; ``lane`` is traced, so
     shapes are the only compile keys (one build per bucket). Pure
     copies — bit-identical to the unfused form by construction."""
-    a3 = a3.at[lane].set(a_p)
-    b3 = b3.at[lane].set(b_p)
-    mask = mask.at[lane].set(m_p)
-    h1 = h1.at[lane].set(h1v)
-    h2 = h2.at[lane].set(h2v)
-    delta = delta.at[lane].set(dv)
+    a3 = put(a3, lane, a_p)
+    b3 = put(b3, lane, b_p)
+    mask = put(mask, lane, m_p)
+    h1 = put(h1, lane, h1v)
+    h2 = put(h2, lane, h2v)
+    delta = put(delta, lane, dv)
     state = tuple(
-        s if i == _IDX["k"] else s.at[lane].set(u[0])
+        s if i == _IDX["k"] else put(s, lane, u[0])
         for i, (s, u) in enumerate(zip(state, unit))
     )
     return a3, b3, mask, h1, h2, delta, state
+
+
+# no donation, matching _bucket_advance: the host re-reads the carry
+# at every boundary, and CPU/CI backends would only warn
+_refill_scatter = jax.jit(_scatter_lane)  # tpulint: disable=TPU004
+
+
+def _park_state(state, lane, put=_set_lane):
+    """The carry with lane ``lane`` parked: zeroed iterate, residual and
+    direction, breakdown flag raised so the loop freezes the lane until
+    the next refill."""
+    state = list(state)
+    for name in ("w", "r", "p"):
+        idx = _IDX[name]
+        state[idx] = put(state[idx], lane,
+                         jnp.zeros(state[idx].shape[1:], state[idx].dtype))
+    for name, value in (("zr", 0.0), ("conv", False), ("bd", True),
+                        ("quar", False)):
+        state[_IDX[name]] = put(state[_IDX[name]], lane, value)
+    return tuple(state)
+
+
+def _put_own_lane(stack, lane, value):
+    """``_set_lane`` on one chip's shard of a lane-sharded stack, inside
+    ``shard_map``: the chip that owns lane ``lane`` writes it, every
+    other chip rewrites one of its own lanes with its old value, so no
+    lane moves between chips. (Left to the partitioner, a lane index
+    known only at run time gathers the whole stack onto every TPU chip,
+    as each eager ``.at[lane].set`` does.)"""
+    from poisson_ellipse_tpu.parallel.batched_sharded import MESH_AXES
+
+    n = stack.shape[0]
+    at = lane - lax.axis_index(MESH_AXES) * n
+    mine = (at >= 0) & (at < n)
+    at = jnp.clip(at, 0, n - 1)
+    return lax.dynamic_update_index_in_dim(
+        stack, jnp.where(mine, value, stack[at]), at, 0
+    )
+
+
+@functools.lru_cache(maxsize=8)
+def _mesh_refill_scatter(layout):
+    """``_scatter_lane`` on a mesh, in ``layout``
+    (``parallel.batched_sharded.lane_layout``), each lane written on
+    its own chip."""
+    specs = tuple(s.spec for s in layout.operands) + (
+        tuple(s.spec for s in layout.state),
+    )
+    mapped = shard_map(
+        functools.partial(_scatter_lane, put=_put_own_lane),
+        mesh=layout.stack.mesh,
+        # the new lane's values come in whole to every chip
+        in_specs=specs + (layout.replicated.spec,) * 8,
+        out_specs=specs,
+    )
+    return jax.jit(mapped, out_shardings=layout.operands + (layout.state,))
+
+
+@functools.lru_cache(maxsize=8)
+def _mesh_park(layout):
+    """``_park_state`` on a mesh, in ``layout``, each lane parked on its
+    own chip."""
+    specs = tuple(s.spec for s in layout.state)
+    mapped = shard_map(
+        functools.partial(_park_state, put=_put_own_lane),
+        mesh=layout.stack.mesh,
+        in_specs=(specs, layout.replicated.spec),
+        out_specs=specs,
+    )
+    return jax.jit(mapped, out_shardings=layout.state)
+
+
+def _fetch_lane(stack, lane: int) -> np.ndarray:
+    """Lane ``lane`` of a per-lane stack, read from the shard that holds
+    it: indexing a lane-sharded array would gather the lane onto every
+    chip first."""
+    for shard in stack.addressable_shards:
+        rows = range(*shard.index[0].indices(stack.shape[0]))
+        if lane in rows:
+            return np.asarray(shard.data[lane - rows.start])
+    raise IndexError(f"lane {lane} lies on no local device")
 
 
 def embed_operands(problem: Problem, bucket: tuple[int, int], np_dtype,
@@ -195,24 +277,34 @@ class _BatchCtx:
         if mesh is not None:
             from poisson_ellipse_tpu.parallel.batched_sharded import (
                 build_sharded_chunk_advance,
+                lane_layout,
             )
 
             self.fn, self.proto = build_sharded_chunk_advance(
                 bucket, mesh=mesh, lanes=lanes, norm=norm,
                 iter_ceiling=ITER_CEILING,
             )
+            # every per-lane array lives on the chip that owns the lane
+            self.layout = lane_layout(mesh)
+            self.scatter = _mesh_refill_scatter(self.layout)
+            self.park = _mesh_park(self.layout)
+            stack, vector = self.layout.stack, self.layout.vector
         else:
             self.fn, self.proto = _bucket_advance(
                 bucket[0], bucket[1], jnp.dtype(dtype).name, norm
             )
+            self.layout = None
+            self.scatter = _refill_scatter
+            self.park = _park_state
+            stack = vector = None
         g = (lanes, bucket[0] + 1, bucket[1] + 1)
-        zeros3 = jnp.zeros(g, dtype)
+        zeros3 = jnp.zeros(g, dtype, device=stack)
         self.a3 = zeros3
         self.b3 = zeros3
         self.mask = zeros3
-        self.h1 = jnp.ones((lanes,), dtype)
-        self.h2 = jnp.ones((lanes,), dtype)
-        self.delta = jnp.full((lanes,), 1e-6, dtype)
+        self.h1 = jnp.ones((lanes,), dtype, device=vector)
+        self.h2 = jnp.ones((lanes,), dtype, device=vector)
+        self.delta = jnp.full((lanes,), 1e-6, dtype, device=vector)
         state = list(batched_pcg.init_state(
             self.proto, self.a3, self.b3, zeros3, mask=self.mask,
             h1=self.h1, h2=self.h2,
@@ -220,8 +312,10 @@ class _BatchCtx:
         # every lane starts parked: the breakdown flag freezes it until
         # a refill swaps a request in (zero-RHS lanes would otherwise
         # burn one iteration reaching the same flag)
-        state[_IDX["bd"]] = jnp.ones((lanes,), bool)
+        state[_IDX["bd"]] = jnp.ones((lanes,), bool, device=vector)
         self.state = tuple(state)
+        if self.layout is not None:
+            self.state = jax.device_put(self.state, self.layout.state)
         self.slots: list[Optional[_InFlight]] = [None] * lanes
         # per-bucket chunk override (None = the scheduler-wide default);
         # set at admission from the autotune registry (Scheduler._ctx_for)
@@ -661,7 +755,8 @@ class Scheduler:
 
         Traced as ``serve.step``, holding a ``serve.refill`` per lane
         swapped in, a ``serve.advance`` (faults, limit, dispatch) and a
-        ``serve.boundary`` (flag reads, retirements, rebase) per bucket."""
+        ``serve.boundary`` (the flag reads in ``serve.flags``,
+        retirements, rebase) per bucket."""
         with obs_trace.span("serve.step"):
             now = self.clock()
             for req in self.queue.expire(now):
@@ -839,7 +934,7 @@ class Scheduler:
             x0=None if x0_p is None else jnp.asarray(x0_p)[None],
         )
         (ctx.a3, ctx.b3, ctx.mask, ctx.h1, ctx.h2, ctx.delta,
-         ctx.state) = _refill_scatter(
+         ctx.state) = ctx.scatter(
             ctx.a3, ctx.b3, ctx.mask, ctx.h1, ctx.h2, ctx.delta,
             ctx.state, unit, a_p, b_p, m_p,
             jnp.asarray(p.h1, ctx.h1.dtype), jnp.asarray(p.h2, ctx.h2.dtype),
@@ -938,19 +1033,8 @@ class Scheduler:
         return False
 
     def _park_lane(self, ctx: _BatchCtx, lane: int) -> None:
-        """Return a lane to the parked pool: zeroed state, breakdown
-        flag raised so the loop freezes it until the next refill."""
-        state = list(ctx.state)
-        for name in ("w", "r", "p"):
-            idx = _IDX[name]
-            state[idx] = state[idx].at[lane].set(
-                jnp.zeros(state[idx].shape[1:], state[idx].dtype)
-            )
-        state[_IDX["zr"]] = state[_IDX["zr"]].at[lane].set(0.0)
-        state[_IDX["conv"]] = state[_IDX["conv"]].at[lane].set(False)
-        state[_IDX["bd"]] = state[_IDX["bd"]].at[lane].set(True)
-        state[_IDX["quar"]] = state[_IDX["quar"]].at[lane].set(False)
-        ctx.state = tuple(state)
+        """Return a lane to the parked pool (``_park_state``)."""
+        ctx.state = ctx.park(ctx.state, lane)
         ctx.slots[lane] = None
 
     # -- retirement ----------------------------------------------------------
@@ -962,11 +1046,13 @@ class Scheduler:
         result beats a miss at the same boundary), then fault
         retirement into the retry ladder, then deadline cancels, then
         per-request iteration caps."""
-        conv = np.asarray(ctx.state[_IDX["conv"]])
-        bd = np.asarray(ctx.state[_IDX["bd"]])
-        quar = np.asarray(ctx.state[_IDX["quar"]])
-        iters = np.asarray(ctx.state[_IDX["iters"]])
-        diffs = np.asarray(ctx.state[_IDX["diff"]])
+        # on a mesh each read gathers the flags from every chip
+        with obs_trace.span("serve.flags"):
+            conv = np.asarray(ctx.state[_IDX["conv"]])
+            bd = np.asarray(ctx.state[_IDX["bd"]])
+            quar = np.asarray(ctx.state[_IDX["quar"]])
+            iters = np.asarray(ctx.state[_IDX["iters"]])
+            diffs = np.asarray(ctx.state[_IDX["diff"]])
         now = self.clock()
         for lane, slot in enumerate(ctx.slots):
             if slot is None:
@@ -1047,7 +1133,7 @@ class Scheduler:
         req = slot.req
         job = self._grad_job(req)
         g1, g2 = req.problem.M + 1, req.problem.N + 1
-        u = np.asarray(ctx.state[_IDX["w"]][lane])[:g1, :g2].copy()
+        u = _fetch_lane(ctx.state[_IDX["w"]], lane)[:g1, :g2].copy()
         if job.stage == "primal":
             pending = job.absorb_primal(u, req_iters)
             self._park_lane(ctx, lane)
@@ -1120,7 +1206,7 @@ class Scheduler:
         w = None
         if self.keep_solutions and (converged or partial):
             g1, g2 = req.problem.M + 1, req.problem.N + 1
-            w = np.asarray(ctx.state[_IDX["w"]][lane])[:g1, :g2].copy()
+            w = _fetch_lane(ctx.state[_IDX["w"]], lane)[:g1, :g2].copy()
         if (converged and slot.cache_key is not None
                 and ctx.pool is not None):
             # the deposit half of the recycle pool: a converged lane's
@@ -1128,7 +1214,7 @@ class Scheduler:
             # same embedded rhs a future consult will sketch on
             ctx.pool.put(
                 slot.cache_key, slot.rhs_pad,
-                np.asarray(ctx.state[_IDX["w"]][lane]).copy(),
+                _fetch_lane(ctx.state[_IDX["w"]], lane).copy(),
                 iters=iters,
             )
         self._park_lane(ctx, lane)

@@ -17,6 +17,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -106,3 +107,63 @@ def test_megakernel_keeps_its_name_in_the_compiled_program(one_chip, engine,
     calls = [line.split(" = ", 1)[0].strip() for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert calls == [f"%{engine}_pcg.1"]
+
+
+def test_served_mesh_programs_keep_each_lane_on_its_chip(one_chip, topo):
+    """serve-400x600-4chip's programs at its size, 16 lanes of the
+    400x600 request's 512x768 bucket on a 2x2: the refill scatter and
+    the lane park hold no collective (left to the partitioner, a lane
+    index known only at run time gathers whole stacks onto every chip),
+    and the chunk advance only its convergence psum, each chip reading
+    its own 4 lanes."""
+    from jax.sharding import Mesh
+
+    from poisson_ellipse_tpu.obs.static_cost import compiled_collectives
+    from poisson_ellipse_tpu.parallel.batched_sharded import (
+        build_sharded_chunk_advance,
+        lane_layout,
+    )
+    from poisson_ellipse_tpu.runtime.compile_cache import grid_bucket
+    from poisson_ellipse_tpu.serve.scheduler import (
+        ITER_CEILING,
+        _mesh_park,
+        _mesh_refill_scatter,
+    )
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("x", "y"))
+    layout = lane_layout(mesh)
+    bucket = grid_bucket(400, 600)
+    lane = (bucket[0] + 1, bucket[1] + 1)
+
+    def shapes(lead, shardings, dtypes):
+        return tuple(
+            jax.ShapeDtypeStruct(lead + (lane if s.spec[1:] else ()), d,
+                                 sharding=s)
+            for s, d in zip(shardings, dtypes))
+
+    f32, i32 = jnp.float32, jnp.int32
+    carry = (i32,) + (f32,) * 5 + (jnp.bool_,) * 3 + (i32,)
+    operands = shapes((16,), layout.operands, (f32,) * 6)
+    state = ((jax.ShapeDtypeStruct((), i32, sharding=layout.replicated),)
+             + shapes((16,), layout.state[1:], carry[1:]))
+    k = jax.ShapeDtypeStruct((), i32, sharding=layout.replicated)
+    unit = (jax.ShapeDtypeStruct((), i32),) + tuple(
+        jax.ShapeDtypeStruct((1,) + x.shape[1:], x.dtype) for x in state[1:])
+    new_lane = jax.ShapeDtypeStruct(lane, f32)
+    scalar = jax.ShapeDtypeStruct((), f32)
+
+    scatter = _mesh_refill_scatter(layout).lower(
+        *operands, state, unit, new_lane, new_lane, new_lane,
+        scalar, scalar, scalar, jax.ShapeDtypeStruct((), i32)).compile()
+    assert set(compiled_collectives(scatter.as_text()).values()) == {0}
+    park = _mesh_park(layout).lower(state, jax.ShapeDtypeStruct((), i32))
+    assert set(compiled_collectives(park.compile().as_text()).values()) \
+        == {0}
+
+    advance, _ = build_sharded_chunk_advance(
+        bucket, mesh=mesh, lanes=16, iter_ceiling=ITER_CEILING)
+    compiled = advance.lower(*operands, state, k).compile()
+    counts = compiled_collectives(compiled.as_text())
+    assert {op: n for op, n in counts.items() if n} == {"all-reduce": 2}
+    stack = 4 * lane[0] * lane[1] * 4  # a chip's 4 float32 lanes
+    assert compiled.memory_analysis().argument_size_in_bytes < 7 * stack

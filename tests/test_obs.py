@@ -645,7 +645,7 @@ def test_phase_timer_is_a_trace_shim(tmp_path):
 
 
 SERVE_SPANS = ("serve.step", "serve.refill", "serve.advance",
-               "serve.boundary", "serve.retire")
+               "serve.boundary", "serve.flags", "serve.retire")
 
 
 def _profiled_spans(trace_dir) -> dict:
@@ -720,6 +720,31 @@ def test_program_spans_nest_on_the_profiler_clock(tmp_path, monkeypatch):
     # each refill assembles once, the resident build twice
     assert len(spans["engine.build"]) == 1
     assert len(spans["assembly.host"]) == 3 + 2
+
+
+def test_flag_reads_are_one_span_inside_each_boundary(tmp_path):
+    """On a mesh, where each flag read gathers from every chip: one
+    ``serve.flags`` span inside every ``serve.boundary``."""
+    from poisson_ellipse_tpu.parallel.mesh import make_mesh
+    from poisson_ellipse_tpu.serve import Scheduler
+
+    sched = Scheduler(lanes=4, chunk=8, mesh=make_mesh(jax.devices()[:4]))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for i in range(6):
+            assert sched.submit(Problem(M=20, N=20),
+                                request_id=f"r{i}") is None
+        results = sched.drain()
+    finally:
+        jax.profiler.stop_trace()
+    assert {r.outcome for r in results.values()} == {"completed"}
+    spans = _profiled_spans(tmp_path)
+    assert len(spans["serve.boundary"]) > 1
+    for start, end, _ in spans["serve.boundary"]:
+        inside = [s for s in spans["serve.flags"]
+                  if start <= s[0] and s[1] <= end]
+        assert len(inside) == 1
+    assert len(spans["serve.flags"]) == len(spans["serve.boundary"])
 
 
 def test_span_is_one_annotation_unless_a_tracer_is_active(tmp_path):

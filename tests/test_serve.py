@@ -650,6 +650,129 @@ def test_scheduler_on_mesh_serves_and_refills():
         )
 
 
+def _lane_arrays(ctx) -> tuple:
+    return ctx.a3, ctx.b3, ctx.mask, ctx.h1, ctx.h2, ctx.delta, ctx.state
+
+
+def _misplaced(arrays, layout) -> list:
+    """Which of a batch's per-lane arrays (``_lane_arrays``) lie anywhere
+    but in ``layout``."""
+    *operands, state = arrays
+    names = ["a3", "b3", "mask", "h1", "h2", "delta"] + [
+        f"state[{i}]" for i in range(len(state))]
+    return [name for name, x, sharding in zip(
+                names, operands + list(state), layout.operands + layout.state)
+            if not x.sharding.is_equivalent_to(sharding, x.ndim)]
+
+
+def test_scheduler_on_mesh_keeps_every_lane_on_its_own_chip():
+    """Operand stacks, spacings, δ and every carry field stay in the
+    chunk advance's lane layout through refills, advances and
+    retirements: no stack sits whole on one chip, to be spread over the
+    mesh again at every chunk."""
+    from poisson_ellipse_tpu.parallel.batched_sharded import lane_layout
+    from poisson_ellipse_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(jax.devices()[:4])
+    layout = lane_layout(mesh)
+    sched = make_scheduler(lanes=8, mesh=mesh)
+    for i in range(10):  # more requests than lanes: retirements refill
+        sched.submit(Problem(M=12, N=12), request_id=f"p{i}")
+    sched.step()
+    (ctx,) = sched._ctxs.values()
+    assert _misplaced(_lane_arrays(ctx), layout) == []
+    while not sched.results:
+        sched.step()
+    assert _misplaced(_lane_arrays(ctx), layout) == []
+    results = sched.drain()
+    assert {r.outcome for r in results.values()} == {"completed"}
+    assert _misplaced(_lane_arrays(ctx), layout) == []
+
+
+def test_mesh_refill_and_park_write_one_lane_and_move_none_between_chips():
+    """The mesh's refill scatter and lane park compile with no all-gather
+    and no all-to-all, write exactly the lane they are given (whichever
+    chip owns it) and keep every output in the lane layout."""
+    from poisson_ellipse_tpu.obs.static_cost import compiled_collectives
+    from poisson_ellipse_tpu.parallel.mesh import make_mesh
+    from poisson_ellipse_tpu.serve.scheduler import (
+        _BatchCtx,
+        _mesh_park,
+        _mesh_refill_scatter,
+    )
+
+    ctx = _BatchCtx((12, 12), lanes=8, dtype=jnp.float32, norm="weighted",
+                    mesh=make_mesh(jax.devices()[:4]))
+    scatter = _mesh_refill_scatter(ctx.layout)
+    lane_shape = ctx.a3.shape[1:]
+    unit = tuple(np.full((1,) + x.shape[1:], 7, x.dtype) if x.ndim
+                 else np.zeros((), x.dtype) for x in ctx.state)
+    new = np.ones(lane_shape, np.float32)
+    scalars = [jnp.float32(2.0)] * 3
+    args = (*_lane_arrays(ctx), unit, new, new, new, *scalars)
+    park = _mesh_park(ctx.layout)
+    for program in (scatter.lower(*args, jnp.int32(0)),
+                    park.lower(ctx.state, jnp.int32(0))):
+        counts = compiled_collectives(program.compile().as_text())
+        assert counts["all-gather"] == counts["all-to-all"] == 0, counts
+    for lane in (0, 5):  # on the first chip, and on the third
+        out = scatter(*args, jnp.asarray(lane, jnp.int32))
+        a3, h1, w = (np.asarray(x) for x in (out[0], out[3], out[6][1]))
+        others = np.arange(8) != lane
+        assert (a3[lane] == 1).all() and (a3[others] == 0).all()
+        assert h1[lane] == 2 and (h1[others] == 1).all()
+        assert (w[lane] == 7).all() and (w[others] == 0).all()
+        assert _misplaced(out, ctx.layout) == []
+        parked = park(out[6], lane)
+        w, bd = np.asarray(parked[1]), np.asarray(parked[7])
+        assert (w == 0).all() and bd.all()
+        assert _misplaced(out[:6] + (parked,), ctx.layout) == []
+
+
+def test_mesh_drain_of_the_six_eps_matches_the_plain_reference():
+    """The cell's six ε at 40x60 through a 2x2-mesh scheduler (2 lanes a
+    chip, so the six requests land on three chips) against the plain
+    PCG of ``benchmark/reference.py``, within the configuration's own
+    ``w_rel_err`` limit: the reference assembles in float64 on the host
+    and iterates in float32 on its own, so it agrees with the program to
+    the float32 rounding of two different summation orders, not bit for
+    bit (the cell's full-size runs read 1.17e-5 against its 2e-4).
+    Against the one-chip scheduler at the same lanes a chip the answers
+    are bit for bit, at the same iteration counts."""
+    import json
+
+    from benchmark import reference
+    from poisson_ellipse_tpu.parallel.mesh import make_mesh
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "ellipse-400x600-2x2.json")) as fh:
+        config = dict(json.load(fh), grid=[40, 60])
+    a1, b1, a2, b2 = config["box"]
+
+    def problem(eps):
+        return Problem(M=40, N=60, a1=a1, b1=b1, a2=a2, b2=b2,
+                       f_val=config["f"], delta=config["delta"],
+                       norm=config["norm"], eps=eps)
+
+    def drain(sched):
+        for i, eps in enumerate(config["eps_choices"]):
+            assert sched.submit(problem(eps), request_id=str(i)) is None
+        return sched.drain()
+
+    mesh = drain(Scheduler(lanes=8, mesh=make_mesh(jax.devices()[:4])))
+    single = drain(Scheduler(lanes=2))
+    for i, eps in enumerate(config["eps_choices"]):
+        got, one = mesh[str(i)], single[str(i)]
+        assert got.outcome == "completed" and got.converged
+        assert got.iters == one.iters
+        np.testing.assert_array_equal(got.w, one.w)
+        w_ref, _, conv = reference.solve(reference.problem_spec(config, eps))
+        assert conv
+        err = np.linalg.norm(got.w - w_ref) / np.linalg.norm(w_ref)
+        assert err <= config["limits"]["w_rel_err"], (eps, err)
+
+
 # -- observability -----------------------------------------------------------
 
 
