@@ -184,6 +184,12 @@ class _Span:
         self._start = time.monotonic()
         return self
 
+    def set_metadata(self, **fields):
+        """Add fields known only inside the span (as ``TraceAnnotation``
+        does): to the record and to the profiler's annotation."""
+        self.fields.update(fields)
+        self.annotation.set_metadata(**fields)
+
     def __exit__(self, exc_type, *exc):
         fields = dict(self.fields)
         lane = fields.pop("lane", None)

@@ -4,9 +4,9 @@
 past ~2400x3200 (f32) the state itself no longer fits and the framework
 previously fell back to the XLA while_loop (~13 modelled HBM passes per
 iteration, measured ~67% of HBM peak at 4096² — the north-star grid).
-This kernel streams EVERYTHING — state and operands — through
-double-buffered tile DMA, and restructures the iteration so the traffic
-floor is lower than XLA's:
+This kernel streams the state (all of it, or all but z) and the
+operands through double-buffered tile DMA, and restructures the
+iteration so the traffic floor is lower than XLA's:
 
 - **z-state form** (as the streamed engine's all-streamed regime): the
   state is (w, z, p) with z = Dinv·r, so the p-update needs no
@@ -32,11 +32,20 @@ floor):
   C   z -= alpha*(Dinv*ap);                       reads  z, dinv, ap
       zr partial = sum(z^2 / Dinv)                writes z
 
-= ~12.08 HBM array-passes/iter vs the XLA loop's ~13, at a higher
-achieved fraction of peak. Measured (bench chip, f32): 4096² = 4.22 s
-vs 5.16 s XLA (1.22×, 3226 iterations exact, 75.5% of HBM peak);
-8192² = 28.7 s / 5889 iterations at 81.3% of peak on ONE chip — a grid
-the reference reaches only on a multi-node MPI cluster. All per-element
+- **resident z** (``XLPlan.z_resident``): where one whole z array and
+  the remaining tile slots fit VMEM (square f32 grids up to 4863² on a
+  128 MiB part, the 4096² north star among them), z lives in a VMEM
+  scratch for the whole solve. AB reads its tile there, C updates it in
+  place: 3 of the 12 passes go, ~9.08 HBM array-passes/iter. Larger
+  grids stream z too: ~12.08 passes/iter vs the XLA loop's ~13. Both
+  forms run the same per-element arithmetic in the same order, so at
+  one tile height they give the same bits.
+
+Measured (TPU v5e, f32): 4096² = 3.00 s for 3226 iterations with z
+resident, 3.99 s with z streamed, both at about 83% of HBM peak, vs
+5.16 s for the XLA loop; 8192² (z streamed) = 28.7 s / 5889 iterations
+at 81.3% of peak on ONE chip — a grid the reference reaches only on a
+multi-node MPI cluster. All per-element
 FP forms are shared with the streamed z-state regime (verified there to
 preserve the published iteration-count oracles); reductions are
 tile-sequential as in every Pallas engine.
@@ -49,8 +58,6 @@ whole solve is ONE kernel launch and the scalars never leave SMEM.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -60,6 +67,7 @@ from jax.experimental.pallas import tpu as pltpu
 from poisson_ellipse_tpu.models.problem import Problem
 from poisson_ellipse_tpu.ops.streamed_pcg import (
     _VMEM_LIMIT,
+    _VMEM_USABLE,
     _interpret_default,
     _round_up,
     _shift_cols_left,
@@ -70,38 +78,72 @@ from poisson_ellipse_tpu.solver.pcg import DENOM_GUARD, PCGResult
 from poisson_ellipse_tpu.utils.device import scaled_vmem_budget
 
 # Candidate row-tile heights for the default policy. Measured at 4096²
-# the timings are flat across 64/96/128 (4.28-4.30 s) while 256 is ~3%
-# slower and 384 overflows VMEM (the kernel holds ~25 tile slots), so
-# the policy just minimises padded rows — at 4097 rows that picks 96
-# (g1p = 4128 vs 4224 with 128), 2.3% less streamed work for free.
+# (all streamed) the timings are flat across 64/96/128 (4.28-4.30 s)
+# while 256 is ~3% slower and 384 overflows VMEM (that form holds ~25
+# tile slots), so the policy minimises padded rows among the tiles whose
+# form fits — at 4097 rows that picks 96 (g1p = 4128 vs 4224 with 128),
+# 2.3% less streamed work for free.
 _TM_CANDIDATES = (64, 96, 128, 256)
+
+# Tile slots of tm rows the z-resident form still holds (p, w, wout, a,
+# b, ap, dv, apc double-buffered + the 3-slot pn ring); ``a`` adds an
+# 8-row halo to each of its 2 slots. The all-streamed form adds z, zc
+# and zcout's double-buffered slots.
+_Z_RESIDENT_SLOTS = 19
+_Z_STREAM_SLOTS = 6
+_A_HALO_ROWS = 16
 
 
 class XLPlan:
-    """Tiling of the XL solve (no residency choices: everything streams).
+    """Tiling of the XL solve, and whether z stays in VMEM.
 
-    ``dtype`` is accepted for interface parity with ``StreamPlan`` but
-    does not influence the tiling: with no residency budget to fill,
-    the tile choice is itemsize-independent (the ~16 tile buffers sit
-    far below VMEM at every candidate size)."""
+    z = Dinv·r is the state vector that costs most to stream (AB reads
+    it, C reads and writes it back: 3 of the 12 passes an iteration), so
+    when one whole padded z array plus the tile slots the resident form
+    still needs fits ``_VMEM_USABLE`` (scaled to the default device's
+    VMEM, as the kernel's own ``vmem_limit_bytes``), z lives in a VMEM
+    scratch for the whole solve (``z_resident``). The default tile is
+    then the least-padded candidate whose resident form fits; when none
+    does, the least-padded candidate whose all-streamed form fits (the
+    largest tile's 25 slots overflow VMEM on rows of 4736 f32 or more).
+    ``dtype`` is the compute dtype: z keeps compute width whatever the
+    operands' storage width."""
 
     def __init__(self, problem: Problem, dtype, tm: int | None = None):
         g1, g2 = problem.node_shape
+        self.g2p = _round_up(g2, 128)
+        row = self.g2p * jnp.dtype(dtype).itemsize
+        budget = scaled_vmem_budget(_VMEM_USABLE)
+
+        def tiling(t):
+            t = t if g1 >= t else _round_up(g1, 8)
+            return t, _round_up(g1, t)
+
+        def fits(t, z_resident):
+            t, g1p = tiling(t)
+            z_rows = g1p if z_resident else _Z_STREAM_SLOTS * t
+            rows = z_rows + _Z_RESIDENT_SLOTS * t + _A_HALO_ROWS
+            return rows * row <= budget
+
         if tm is None:
             # least padded rows; larger tile breaks ties (fewer steps)
-            tm = min(_TM_CANDIDATES, key=lambda t: (_round_up(g1, t), -t))
+            tm = min(
+                [t for t in _TM_CANDIDATES if fits(t, True)]
+                or [t for t in _TM_CANDIDATES if fits(t, False)]
+                or _TM_CANDIDATES,
+                key=lambda t: (_round_up(g1, t), -t),
+            )
         if tm % 8 or tm < 8:
             raise ValueError(f"tm must be a positive multiple of 8, got {tm}")
-        self.g2p = _round_up(g2, 128)
-        self.tm = tm if g1 >= tm else _round_up(g1, 8)
-        self.g1p = _round_up(g1, self.tm)
+        self.z_resident = fits(tm, True)
+        self.tm, self.g1p = tiling(tm)
         self.n_tiles = self.g1p // self.tm
 
     def passes_per_iter(self) -> float:
         """Modelled HBM array-passes per iteration (roofline report)."""
-        # AB: z r, p r, w r+w, pn w, ap w, a r (+8-row halo), b r;
-        # C: z r+w, dinv r, ap r
-        return 12.0 + 8.0 / self.tm
+        # AB: p r, w r+w, pn w, ap w, a r (+8-row halo), b r;
+        # C: dinv r, ap r; streamed z adds AB's z r and C's z r+w
+        return (9.0 if self.z_resident else 12.0) + 8.0 / self.tm
 
 
 def _sem_map():
@@ -119,16 +161,20 @@ def _sem_map():
 _SEM, _NSEMS = _sem_map()
 
 
-def _mega_kernel(problem: Problem, plan: XLPlan, weighted: bool,
+def _mega_kernel(problem: Problem, plan: XLPlan, weighted: bool, *,
                  # HBM inputs
                  dinv_hbm, a_hbm, b_hbm, r0_hbm,
-                 # outputs (w is the result; z/p/ap are HBM scratch)
-                 w_hbm, iters_out, diff_out, flags_out,
-                 z_hbm, p_hbm, ap_hbm,
+                 # outputs (w is the result; p/ap are HBM scratch)
+                 w_hbm, iters_out, diff_out, flags_out, p_hbm, ap_hbm,
                  # VMEM tile buffers + SMEM accumulators
-                 z_buf, p_buf, w_buf, wout_buf, ring, a_buf, b_buf,
-                 ap_buf, zc_buf, zcout_buf, dv_buf, apc_buf, acc, sems):
+                 p_buf, w_buf, wout_buf, ring, a_buf, b_buf, ap_buf,
+                 dv_buf, apc_buf, acc, sems,
+                 # z: one whole VMEM array (plan.z_resident), or an HBM
+                 # scratch with its AB, C-read and C-write tile buffers
+                 z_vmem=None, z_hbm=None, z_buf=None, zc_buf=None,
+                 zcout_buf=None):
     dtype = r0_hbm.dtype
+    z_resident = plan.z_resident
     tm, g2p, n_tiles = plan.tm, plan.g2p, plan.n_tiles
     h1h2 = jnp.asarray(float(problem.h1) * float(problem.h2), dtype)
     delta = jnp.asarray(problem.delta, dtype)
@@ -138,10 +184,14 @@ def _mega_kernel(problem: Problem, plan: XLPlan, weighted: bool,
     _HBM = {"z": z_hbm, "p": p_hbm, "w": w_hbm, "dv": dinv_hbm,
             "a": a_hbm, "b": b_hbm, "zc": z_hbm, "apc": ap_hbm,
             "r0": r0_hbm}
+    # r0 lands where the init sweep leaves a buffer idle
     _BUF = {"z": z_buf, "p": p_buf, "w": w_buf, "dv": dv_buf,
             "a": a_buf, "b": b_buf, "zc": zc_buf, "apc": apc_buf,
-            "r0": zc_buf}
+            "r0": apc_buf if z_resident else zc_buf}
     _ROWS = {"a": tm + 8}
+    # the streams of each sweep; a resident z streams in neither
+    ab_streams = ("p", "w") if z_resident else ("z", "p", "w")
+    c_streams = ("dv", "apc") if z_resident else ("zc", "dv", "apc")
 
     def load(name, t, slot):
         rows = _ROWS.get(name, tm)
@@ -177,12 +227,15 @@ def _mega_kernel(problem: Problem, plan: XLPlan, weighted: bool,
             cp = load(name, t, 0)
             cp.start()
             cp.wait()
-        rt = tile_of(zc_buf, 0)
+        rt = tile_of(_BUF["r0"], 0)
         zt = rt * tile_of(dv_buf, 0)
-        zcout_buf[pl.ds(0, tm), :] = zt
-        for name, buf, hbm in (("zst", zcout_buf, z_hbm),
-                               ("wst", w_buf, w_hbm),
-                               ("pnst", w_buf, p_hbm)):
+        stores = [("wst", w_buf, w_hbm), ("pnst", w_buf, p_hbm)]
+        if z_resident:
+            z_vmem[pl.ds(t * tm, tm), :] = zt
+        else:
+            zcout_buf[pl.ds(0, tm), :] = zt
+            stores.insert(0, ("zst", zcout_buf, z_hbm))
+        for name, buf, hbm in stores:
             cp = store(name, buf, hbm, t, 0)
             cp.start()
             cp.wait()
@@ -243,13 +296,13 @@ def _mega_kernel(problem: Problem, plan: XLPlan, weighted: bool,
 
         # ---- AB sweep: step t updates tile t (w += alpha p, pn = z +
         # beta p) and stencils tile t-1 (ring holds pn tiles t-2..t).
-        # State loads (z/p/w) for tile t are prefetched at step t-1 into
-        # slot t%2; a/b for stencil s are prefetched at step s into slot
-        # s%2 and consumed at step s+1 — in-use and in-flight slots stay
-        # disjoint for every stream.
+        # State loads (p/w, and z when streamed) for tile t are
+        # prefetched at step t-1 into slot t%2; a/b for stencil s are
+        # prefetched at step s into slot s%2 and consumed at step s+1 —
+        # in-use and in-flight slots stay disjoint for every stream.
         acc[0] = jnp.zeros((), dtype)   # dw2
         acc[1] = jnp.zeros((), dtype)   # denom partial
-        for name in ("z", "p", "w"):
+        for name in ab_streams:
             load(name, 0, 0).start()
 
         def ab_step(t, carry):
@@ -259,13 +312,13 @@ def _mega_kernel(problem: Problem, plan: XLPlan, weighted: bool,
             @pl.when(t + 1 < n_tiles)
             def _():
                 nslot = lax.rem(t + 1, 2)
-                for name in ("z", "p", "w"):
+                for name in ab_streams:
                     load(name, t + 1, nslot).start()
 
             # ---- update phase for tile t
             @pl.when(t < n_tiles)
             def _():
-                for name in ("z", "p", "w"):
+                for name in ab_streams:
                     load(name, t, slot2).wait()
                 # stencil operands for this tile, consumed next step
                 load("a", t, slot2).start()
@@ -281,7 +334,8 @@ def _mega_kernel(problem: Problem, plan: XLPlan, weighted: bool,
 
                 pt = tile_of(p_buf, slot2)
                 wt = tile_of(w_buf, slot2)
-                zt = tile_of(z_buf, slot2)
+                zt = (z_vmem[pl.ds(t * tm, tm), :] if z_resident
+                      else tile_of(z_buf, slot2))
                 w_new = wt + alpha * pt
                 dw = w_new - wt
                 wout_buf[pl.ds(slot2 * tm, tm), :] = w_new
@@ -341,7 +395,7 @@ def _mega_kernel(problem: Problem, plan: XLPlan, weighted: bool,
 
         # ---- C sweep: z update + zr partial off one dinv stream
         acc[2] = jnp.zeros((), dtype)
-        for name in ("zc", "dv", "apc"):
+        for name in c_streams:
             load(name, 0, 0).start()
 
         def c_step(t, carry):
@@ -350,22 +404,27 @@ def _mega_kernel(problem: Problem, plan: XLPlan, weighted: bool,
             @pl.when(t + 1 < n_tiles)
             def _():
                 nslot = lax.rem(t + 1, 2)
-                for name in ("zc", "dv", "apc"):
+                for name in c_streams:
                     load(name, t + 1, nslot).start()
 
-            for name in ("zc", "dv", "apc"):
+            for name in c_streams:
                 load(name, t, slot2).wait()
 
-            @pl.when(t >= 2)
-            def _():
-                store("zst", zcout_buf, z_hbm, t - 2, slot2).wait()
+            if not z_resident:
+                @pl.when(t >= 2)
+                def _():
+                    store("zst", zcout_buf, z_hbm, t - 2, slot2).wait()
 
             dvt = tile_of(dv_buf, slot2)
-            z_new = tile_of(zc_buf, slot2) - alpha_new * (
-                dvt * tile_of(apc_buf, slot2)
-            )
-            zcout_buf[pl.ds(slot2 * tm, tm), :] = z_new
-            store("zst", zcout_buf, z_hbm, t, slot2).start()
+            zt = (z_vmem[pl.ds(t * tm, tm), :] if z_resident
+                  else tile_of(zc_buf, slot2))
+            z_new = zt - alpha_new * (dvt * tile_of(apc_buf, slot2))
+            if z_resident:
+                # in place: no other step of the sweep touches tile t
+                z_vmem[pl.ds(t * tm, tm), :] = z_new
+            else:
+                zcout_buf[pl.ds(slot2 * tm, tm), :] = z_new
+                store("zst", zcout_buf, z_hbm, t, slot2).start()
             # guarded reciprocal: d = 1/Dinv on the interior, 0 off it
             dt = jnp.where(
                 dvt != 0.0,
@@ -380,8 +439,9 @@ def _mega_kernel(problem: Problem, plan: XLPlan, weighted: bool,
             return carry
 
         lax.fori_loop(0, n_tiles, c_step, 0)
-        for tt in range(max(n_tiles - 2, 0), n_tiles):
-            store("zst", zcout_buf, z_hbm, tt, tt % 2).wait()
+        if not z_resident:
+            for tt in range(max(n_tiles - 2, 0), n_tiles):
+                store("zst", zcout_buf, z_hbm, tt, tt % 2).wait()
         zr_new = acc[2] * h1h2
 
         zr_out = jnp.where(breakdown, zr, zr_new)
@@ -403,20 +463,19 @@ def _mega_kernel(problem: Problem, plan: XLPlan, weighted: bool,
 
 
 def build_xl_solver(problem: Problem, dtype=jnp.float32, interpret=None,
-                    tm: int | None = None, _debug_raw: bool = False,
-                    geometry=None, theta=None, storage_dtype=None):
+                    tm: int | None = None, geometry=None, theta=None,
+                    storage_dtype=None):
     """(jitted whole-solve kernel, args) for state-beyond-VMEM grids.
 
     args = (dinv, a, b, r0): f64-assembled, rounded once — the shared
-    operand fidelity contract (``fused_pcg.build_fused_solver``).
-    _debug_raw returns the raw pallas outputs (w, iters, diff, flags,
-    z, p, ap) — the HBM state scratch is inspectable for tests/debug.
+    operand fidelity contract (``fused_pcg.build_fused_solver``). The
+    jitted solver carries the ``XLPlan`` it was built with as ``.plan``.
 
     ``storage_dtype`` (``ops.precision``) streams the coefficient
     operands (dinv, a, b) at that width, upcast per tile inside the
-    kernel (``tile_of``); the HBM state scratch stays at compute width —
-    the operand share of this engine's ~12 passes/iter narrows, the
-    state share keeps full precision (the conservative rung; the full
+    kernel (``tile_of``); the state scratch (and a resident z) stays at
+    compute width — the operand share of this engine's passes narrows,
+    the state share keeps full precision (the conservative rung; the full
     state-narrow form is the sharded/sstep engines' territory).
     """
     from poisson_ellipse_tpu.ops.precision import resolve_storage_dtype
@@ -438,45 +497,55 @@ def build_xl_solver(problem: Problem, dtype=jnp.float32, interpret=None,
             jnp.asarray(b0).astype(st), r00,
         )
 
-    kernel = functools.partial(
-        _mega_kernel, problem, plan, problem.norm == "weighted"
-    )
     anyspec = lambda: pl.BlockSpec(memory_space=pl.ANY)
     smem = lambda: pl.BlockSpec(memory_space=pltpu.SMEM)
     tile = lambda slots, rows=None, narrow=False: pltpu.VMEM(
         (slots * (rows if rows else tm), g2p),
         st if (narrow and st is not None) else dtype,
     )
+    full = jax.ShapeDtypeStruct((g1p, g2p), dtype)
+    # (name, spec, shape) per output; w is the result, the rest scratch
+    outputs = [
+        ("w_hbm", anyspec(), full),
+        ("iters_out", smem(), jax.ShapeDtypeStruct((1,), jnp.int32)),
+        ("diff_out", smem(), jax.ShapeDtypeStruct((1,), dtype)),
+        ("flags_out", smem(), jax.ShapeDtypeStruct((2,), jnp.int32)),
+        ("p_hbm", anyspec(), full),
+        ("ap_hbm", anyspec(), full),
+    ]
+    scratch = [
+        ("p_buf", tile(2)),
+        ("w_buf", tile(2)),
+        ("wout_buf", tile(2)),
+        ("ring", tile(3)),                      # pn
+        ("a_buf", tile(2, tm + 8, narrow=True)),
+        ("b_buf", tile(2, narrow=True)),
+        ("ap_buf", tile(2)),
+        ("dv_buf", tile(2, narrow=True)),
+        ("apc_buf", tile(2)),
+    ]
+    if plan.z_resident:
+        scratch.append(("z_vmem", pltpu.VMEM((g1p, g2p), dtype)))
+    else:
+        outputs.append(("z_hbm", anyspec(), full))
+        scratch += [("z_buf", tile(2)), ("zc_buf", tile(2)),
+                    ("zcout_buf", tile(2))]
+    scratch += [("acc", pltpu.SMEM((3,), dtype)),
+                ("sems", pltpu.SemaphoreType.DMA((_NSEMS,)))]
+    ref_names = (("dinv_hbm", "a_hbm", "b_hbm", "r0_hbm")
+                 + tuple(n for n, _, _ in outputs)
+                 + tuple(n for n, _ in scratch))
+
+    def kernel(*refs):
+        _mega_kernel(problem, plan, problem.norm == "weighted",
+                     **dict(zip(ref_names, refs)))
+
     call = pl.pallas_call(
         kernel,
         in_specs=[anyspec()] * 4,
-        out_specs=(anyspec(), smem(), smem(), smem(),
-                   anyspec(), anyspec(), anyspec()),
-        out_shape=(
-            jax.ShapeDtypeStruct((g1p, g2p), dtype),       # w (result)
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-            jax.ShapeDtypeStruct((1,), dtype),
-            jax.ShapeDtypeStruct((2,), jnp.int32),
-            jax.ShapeDtypeStruct((g1p, g2p), dtype),       # z scratch
-            jax.ShapeDtypeStruct((g1p, g2p), dtype),       # p scratch
-            jax.ShapeDtypeStruct((g1p, g2p), dtype),       # ap scratch
-        ),
-        scratch_shapes=[
-            tile(2),            # z_buf
-            tile(2),            # p_buf
-            tile(2),            # w_buf
-            tile(2),            # wout_buf
-            tile(3),            # ring (pn)
-            tile(2, tm + 8, narrow=True),    # a_buf
-            tile(2, narrow=True),            # b_buf
-            tile(2),            # ap_buf
-            tile(2),            # zc_buf
-            tile(2),            # zcout_buf
-            tile(2, narrow=True),            # dv_buf
-            tile(2),            # apc_buf
-            pltpu.SMEM((3,), dtype),
-            pltpu.SemaphoreType.DMA((_NSEMS,)),
-        ],
+        out_specs=tuple(spec for _, spec, _ in outputs),
+        out_shape=tuple(shape for _, _, shape in outputs),
+        scratch_shapes=[shape for _, shape in scratch],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=scaled_vmem_budget(_VMEM_LIMIT)
         ),
@@ -484,13 +553,10 @@ def build_xl_solver(problem: Problem, dtype=jnp.float32, interpret=None,
         name="xl_pcg",
     )
 
-    if _debug_raw:
-        return jax.jit(call), args
-
     # the jitted function's name is the custom call's name in the device
     # trace: one stable name per engine
     def xl_pcg(dinv, a, b, r0):
-        w_pad, iters, diff, flags, _z, _p, _ap = call(dinv, a, b, r0)
+        w_pad, iters, diff, flags = call(dinv, a, b, r0)[:4]
         return PCGResult(
             w=w_pad[:g1, :g2],
             iters=iters[0],
@@ -502,7 +568,9 @@ def build_xl_solver(problem: Problem, dtype=jnp.float32, interpret=None,
     # no donation: build-once-call-many — callers re-feed these operands
     # every dispatch (bench --repeat protocol)
     # tpulint: disable=TPU004
-    return jax.jit(xl_pcg), args
+    solver = jax.jit(xl_pcg)
+    solver.plan = plan
+    return solver, args
 
 
 def solve_xl(problem: Problem, dtype=jnp.float32, interpret=None) -> PCGResult:

@@ -70,8 +70,8 @@ collective-latency-bound scale. Iteration counts land within ±2 of xla
 Past the streamed gate (~2400x3200 f32; e.g. the 4096² north-star grid,
 whose state alone is ~200 MB) solves are HBM-bandwidth-bound; the xl
 kernel restructures the iteration below the XLA loop's traffic floor
-(z-state + deferred w-update: ~12.1 array-passes/iter vs ~13, at a
-higher achieved fraction of peak — measured 4.28 s vs 5.16 s at 4096²).
+(z-state + deferred w-update: ~12.1 array-passes/iter vs ~13, ~9.1 where
+z fits VMEM, as at 4096²; measured 3.00 s vs 5.16 s at 4096²).
 The framework's *scaling* answer at that size remains the sharded mesh
 path (``parallel.pcg_sharded``), which divides the state over devices
 until it is VMEM-resident again.
@@ -314,9 +314,10 @@ def select_engine(problem: Problem, dtype=jnp.float32, device=None) -> str:
     if fits_streamed(problem, dtype, device):
         return "streamed"
     # past the streamed gate the state itself exceeds VMEM: the xl
-    # kernel streams state AND operands (12.1 passes/iter at ~72% of
-    # HBM peak vs the XLA loop's 13 at ~67% — measured 4.28 s vs 5.16 s
-    # at the 4096² north-star grid)
+    # kernel streams the operands and the state, all of it or all but z
+    # (9.08 passes/iter with z resident, 12.08 streamed, both at about
+    # 83% of HBM peak, vs the XLA loop's 13 at ~67% — measured 3.00 s
+    # resident, 3.99 s streamed, vs 5.16 s at the 4096² north-star grid)
     return "xl"
 
 
@@ -375,14 +376,19 @@ def build_solver(
     fact. Explicitly requested engines always fail loudly.
 
     Traced as one ``engine.build`` span per call (``engine`` the name
-    asked for); ``auto``'s probe is its ``engine.lower`` and
+    asked for; an ``xl`` build adds its plan's ``z_resident`` and
+    ``tm``); ``auto``'s probe is its ``engine.lower`` and
     ``engine.compile`` spans, the host assembly ``assembly.host``.
     """
-    with obs_trace.span("engine.build", engine=engine):
-        return build_solver_body(
+    with obs_trace.span("engine.build", engine=engine) as span:
+        built = build_solver_body(
             problem, engine, dtype, interpret, history, lanes, geometry,
             theta, validate_geometry, storage_dtype, sstep_s, tuned_knobs,
         )
+        if built[2] == "xl":
+            plan = built[0].plan
+            span.set_metadata(z_resident=plan.z_resident, tm=plan.tm)
+        return built
 
 
 def build_solver_body(problem, engine, dtype, interpret, history, lanes,

@@ -74,6 +74,31 @@ def test_engine_compiles_a_mosaic_kernel_for_v5e(one_chip, engine, grid):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("grid,tm,z_resident", [
+    ((4863, 4863), 64, True),    # the largest square grid whose z fits
+    ((3200, 4800), 96, True),    # non-square, past the streamed gate
+    ((5119, 5119), 128, False),  # all streamed: tm 256 pads no less,
+                                 # but its 25 slots overflow VMEM
+])
+def test_xl_plan_compiles_at_its_vmem_edges_for_v5e(one_chip, grid, tm,
+                                                    z_resident):
+    """The xl plan keeps z in VMEM up to 4864 padded rows (118.6 MB of
+    scratch at tm 64 against the 119.5 MB budget), and past that picks a
+    tile whose all-streamed slots fit: Mosaic has to place them beside
+    its own temporaries in the chip's 128 MiB."""
+    from poisson_ellipse_tpu.solver.engine import select_engine
+
+    problem = Problem(M=grid[0], N=grid[1])
+    assert select_engine(problem, jnp.float32) == "xl"
+    solver, args, _ = build_solver(problem, "xl", jnp.float32,
+                                   interpret=False)
+    assert solver.plan.z_resident is z_resident and solver.plan.tm == tm
+    specs = [jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+             for x in args]
+    compiled = solver.lower(*specs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.parametrize("grid", [2048, 4096])
 def test_sharded_loop_keeps_blocks_in_their_layout(one_chip, topo, grid):
     """The 2x2 sharded PCG loop body holds no whole-block copy, pad or

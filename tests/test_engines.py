@@ -9,6 +9,8 @@ policy checks. Pallas kernels run in interpret mode on the CPU backend
 (the engines' own ``_interpret_default``), so this suite needs no TPU.
 """
 
+import contextlib
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -270,19 +272,83 @@ def test_xl_plan_tile_policy_and_forced_tiles():
     multi-tile ring/store-lag pipeline on a grid tests can afford."""
     plan = XLPlan(Problem(M=4096, N=4096), jnp.float32)
     assert plan.tm == 96 and plan.g1p == 4128
+    # z stays in VMEM at 4096²: its 3 passes of the 12 go
     assert XLPlan(Problem(M=4096, N=4096), jnp.float32).passes_per_iter() \
-        == pytest.approx(12.0 + 8.0 / 96)
+        == pytest.approx(9.0 + 8.0 / 96)
     with pytest.raises(ValueError, match="multiple of 8"):
         XLPlan(Problem(M=100, N=100), jnp.float32, tm=100)
+    from poisson_ellipse_tpu.resilience.faultinject import simulated_vmem
+
     problem = Problem(M=40, N=40)
     ref = solve_xla(problem, jnp.float32)
-    for tm in (8, 16):
-        solver, args = build_xl_solver(problem, tm=tm)
-        got = solver(*args)
-        assert int(got.iters) == int(ref.iters) == 50, tm
-        np.testing.assert_allclose(
-            np.asarray(got.w), np.asarray(ref.w), atol=5e-6
-        )
+    # both forms: z resident, and (on a part too small for z) streamed
+    for vmem, z_resident in ((None, True), (64 * 1024, False)):
+        for tm in (8, 16):
+            with simulated_vmem(vmem) if vmem else contextlib.nullcontext():
+                solver, args = build_xl_solver(problem, tm=tm)
+            assert solver.plan.z_resident is z_resident
+            got = solver(*args)
+            assert int(got.iters) == int(ref.iters) == 50, tm
+            np.testing.assert_allclose(
+                np.asarray(got.w), np.asarray(ref.w), atol=5e-6
+            )
+
+
+def test_xl_plan_keeps_z_resident_where_it_fits():
+    """One padded f32 z at 4096² (69.75 MB) plus the resident form's
+    tile slots fits the 128 MiB part's budget; at 8192² (273 MB) it
+    cannot, and everything streams as before."""
+    plan = XLPlan(Problem(M=4096, N=4096), jnp.float32)
+    assert plan.z_resident and plan.tm == 96
+    assert plan.passes_per_iter() == pytest.approx(9.0 + 8.0 / 96)
+    # the edge: 4863² keeps z at tm 64 (4864 padded rows); one more
+    # node row pads to 4896 at every tile, past the budget
+    edge = XLPlan(Problem(M=4863, N=4863), jnp.float32)
+    assert edge.z_resident and edge.tm == 64 and edge.g1p == 4864
+    assert not XLPlan(Problem(M=4864, N=4864), jnp.float32).z_resident
+    big = XLPlan(Problem(M=8192, N=8192), jnp.float32)
+    assert not big.z_resident
+    assert big.tm == 96 and big.g1p == 8256
+    assert big.passes_per_iter() == pytest.approx(12.0 + 8.0 / big.tm)
+
+
+def test_xl_plan_streams_z_under_a_smaller_vmem():
+    """On a part whose budget cannot hold z, the 4096² plan falls back
+    to the all-streamed form and its least-padded tile that fits."""
+    from poisson_ellipse_tpu.resilience.faultinject import simulated_vmem
+
+    with simulated_vmem(64 * 1024 * 1024):
+        plan = XLPlan(Problem(M=4096, N=4096), jnp.float32)
+    assert not plan.z_resident
+    assert plan.tm == 96 and plan.g1p == 4128
+    assert plan.passes_per_iter() == pytest.approx(12.0 + 8.0 / 96)
+    # streamed, the tile still has to fit: at 5120 rows 64, 128 and 256
+    # pad none, and 256's 25 slots of 20 KB rows overflow the budget
+    wide = XLPlan(Problem(M=5119, N=5119), jnp.float32)
+    assert not wide.z_resident and wide.tm == 128 and wide.g1p == 5120
+
+
+@pytest.mark.parametrize("storage_dtype", [None, "bf16"])
+@pytest.mark.parametrize("tm", [8, 16])
+def test_xl_resident_z_matches_streamed_z_bit_for_bit(tm, storage_dtype):
+    """The z-resident kernel and the all-streamed one run the same
+    per-element arithmetic in the same tile order: the same w, the same
+    iteration count and the same final step norm, bit for bit, with the
+    operands streamed at compute width or narrowed."""
+    from poisson_ellipse_tpu.resilience.faultinject import simulated_vmem
+
+    problem = Problem(M=40, N=40)
+    solver, args = build_xl_solver(problem, tm=tm,
+                                   storage_dtype=storage_dtype)
+    assert solver.plan.z_resident
+    with simulated_vmem(64 * 1024):
+        streamed, s_args = build_xl_solver(problem, tm=tm,
+                                           storage_dtype=storage_dtype)
+    assert not streamed.plan.z_resident
+    got, ref = solver(*args), streamed(*s_args)
+    assert int(got.iters) == int(ref.iters) == 50
+    assert float(got.diff) == float(ref.diff)
+    np.testing.assert_array_equal(np.asarray(got.w), np.asarray(ref.w))
 
 
 def test_stream_plan_shapes():
@@ -405,6 +471,10 @@ def test_roofline_passes_model():
         plan.streamed_passes_per_iter()
     )
     assert plan.streamed_passes_per_iter() > 0
+    # xl follows its plan: z resident at the 4096² north star
+    assert passes_per_iter(Problem(M=4096, N=4096), "xl") == pytest.approx(
+        9.0 + 8.0 / 96
+    )
     with pytest.raises(ValueError, match="traffic model"):
         passes_per_iter(p_small, "cuda")
 

@@ -722,6 +722,28 @@ def test_program_spans_nest_on_the_profiler_clock(tmp_path, monkeypatch):
     assert len(spans["assembly.host"]) == 3 + 2
 
 
+def test_xl_build_span_carries_its_plan(tmp_path):
+    """An xl build's ``engine.build`` span says whether its plan keeps z
+    in VMEM, and at which tile: in the JSONL record and on the profiler's
+    clock."""
+    from poisson_ellipse_tpu.solver.engine import build_solver
+
+    sink = io.StringIO()
+    obs_trace.start(sink)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        build_solver(Problem(M=40, N=40), "xl", interpret=True)
+    finally:
+        jax.profiler.stop_trace()
+        obs_trace.stop()
+    recs = [json.loads(line) for line in sink.getvalue().splitlines()]
+    (build,) = [r for r in recs if r["name"] == "engine.build"]
+    assert build["fields"] == {"engine": "xl", "z_resident": True, "tm": 48}
+    (span,) = _profiled_spans(tmp_path)["engine.build"]
+    # the profiler keeps a bool as the integer 1
+    assert span[2] == {"engine": "xl", "z_resident": 1, "tm": 48}
+
+
 def test_flag_reads_are_one_span_inside_each_boundary(tmp_path):
     """On a mesh, where each flag read gathers from every chip: one
     ``serve.flags`` span inside every ``serve.boundary``."""

@@ -19,6 +19,8 @@ Covers the two new axes end to end:
 
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -299,17 +301,26 @@ def test_raw_bf16_classical_converges_and_carries_bf16_state():
 
 def test_streamed_and_xl_narrow_operand_streams():
     """streamed/xl: bf16 operand streaming converges at the f32 cell's
-    iteration count (the operator rounds once; state stays full-width)."""
+    iteration count (the operator rounds once; state stays full-width).
+    xl runs both its forms: z resident, and z streamed on a part whose
+    VMEM cannot hold it."""
     from poisson_ellipse_tpu.ops.streamed_pcg import build_streamed_solver
     from poisson_ellipse_tpu.ops.xl_pcg import build_xl_solver
+    from poisson_ellipse_tpu.resilience.faultinject import simulated_vmem
 
     problem = Problem(M=20, N=20)
-    for build in (build_streamed_solver, build_xl_solver):
-        s_full, a_full = build(problem, jnp.float32, interpret=True)
+    for build, vmem in ((build_streamed_solver, None),
+                        (build_xl_solver, None),
+                        (build_xl_solver, 64 * 1024)):
+        with simulated_vmem(vmem) if vmem else contextlib.nullcontext():
+            s_full, a_full = build(problem, jnp.float32, interpret=True)
+            s_bf, a_bf = build(
+                problem, jnp.float32, interpret=True, storage_dtype="bf16"
+            )
+        if build is build_xl_solver:
+            assert s_full.plan.z_resident is s_bf.plan.z_resident is (
+                vmem is None)
         r_full = s_full(*a_full)
-        s_bf, a_bf = build(
-            problem, jnp.float32, interpret=True, storage_dtype="bf16"
-        )
         assert a_bf[0].dtype == jnp.bfloat16  # dinv streams narrow
         assert a_bf[3].dtype == jnp.float32   # r0 stays compute-width
         r_bf = s_bf(*a_bf)
