@@ -29,7 +29,7 @@ The invariants the replay preserves:
 
 Handoff latency (journal open → last adoption) is measured per handoff
 (``handoff_latency_seconds`` histogram) — it is the fleet's
-recovery-time story, and ``bench.py``'s fleet key reports its p99.
+recovery-time story.
 """
 
 from __future__ import annotations

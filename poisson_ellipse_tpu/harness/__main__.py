@@ -2048,7 +2048,7 @@ def _run_cli(args) -> int:
                             "note: single-dispatch T_solver includes the "
                             "fixed per-dispatch host overhead; pass e.g. "
                             "--repeat 3 --batch 5 for the amortised "
-                            "protocol bench.py uses",
+                            "protocol",
                             file=sys.stderr,
                         )
                 print()

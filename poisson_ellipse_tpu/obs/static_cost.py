@@ -24,8 +24,8 @@ This module does that reading, with no hardware in the loop:
   columns ``harness inspect`` prints and BENCH artifacts carry.
 
 The "pipelined = 1 psum/iter vs classical = 2" regression check lives on
-top of this module (``tests/test_obs.py``, ``tests/test_pipelined.py``,
-``bench.py``'s artifact) — one metric, asserted everywhere it matters,
+top of this module (``tests/test_obs.py``, ``tests/test_pipelined.py``)
+— one metric, asserted everywhere it matters,
 instead of test-local jaxpr walks.
 """
 

@@ -514,7 +514,7 @@ def select(problem: Problem, telemetry: dict, dtype=jnp.float32,
 def _measure_once(problem: Problem, engine: str, dtype, geometry=None,
                   theta=None, knobs: dict | None = None) -> float:
     """One warmed, fenced dispatch's wall clock (the tune-time check,
-    not the bench protocol — bench.py owns the amortised numbers).
+    not a benchmark: ``benchmark/`` owns the measured numbers).
     ``knobs`` is the candidate's knob dict: the measured configuration
     must BE the scored configuration (levels/ν/degrees/n_vcycles via
     ``tuned_knobs``, s via ``sstep_s``), or the persisted record would

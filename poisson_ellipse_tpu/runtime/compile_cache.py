@@ -9,7 +9,7 @@ worker). Two layers here, one per failure mode:
   — JAX's on-disk cache with the min-compile-time gate dropped to zero,
   so every compiled solver (any engine) lands on disk and a restarted
   worker deserialises instead of recompiling. Every entry point (the
-  harness CLI, ``bench.py``, ``chip_smoke.py``) turns it on at start.
+  harness CLI, ``chip_smoke.py``, ``benchmark/``) turns it on at start.
   It lives in ``$JAX_COMPILATION_CACHE_DIR`` when that is set, else at
   the fixed ``<repo>/.jax_cache/`` — the path is part of the cache key,
   so it never moves.

@@ -14,9 +14,8 @@ stream keeps going. Everything is deterministic in ``seed``: the same
 chaos reproduces bit-for-bit, which is what makes a failing run
 debuggable instead of an anecdote.
 
-``run_chaos`` is the single entry shared by ``tests/test_serve.py``,
-the ``harness chaos`` subcommand, and the ``bench.py`` serving key's
-sanity half.
+``run_chaos`` is the single entry shared by ``tests/test_serve.py``
+and the ``harness chaos`` subcommand.
 """
 
 from __future__ import annotations

@@ -487,18 +487,21 @@ def test_lanes_reject_non_batched_engines(problem):
         run_once(problem, lanes=2, checkpoint_dir="/tmp/nope")
 
 
-def test_lanes_with_chained_timing_protocol(problem):
+@pytest.mark.parametrize("lanes", [1, 2, 8])
+def test_lanes_with_chained_timing_protocol(problem, lanes):
     # --lanes (real batching) composes with --batch (the chained timing
     # protocol): the marginal-cost measurement runs over the batched
-    # solver without perturbing its per-lane results
+    # solver without perturbing its per-lane results — every lane at the
+    # single-solve oracle, none quarantined, at each width
     from poisson_ellipse_tpu.harness.run import run_once
 
     report = run_once(
-        problem, mode="single", engine="batched", lanes=2, repeat=1,
+        problem, mode="single", engine="batched", lanes=lanes, repeat=1,
         batch=2,
     )
     assert report.converged and report.iters == 50
-    assert report.lanes == 2 and report.solves_per_sec > 0
+    assert report.quarantined == 0
+    assert report.lanes == lanes and report.solves_per_sec > 0
 
 
 def test_guarded_lanes_run(problem):

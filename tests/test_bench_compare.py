@@ -330,7 +330,7 @@ def test_one_sided_keys_are_skipped_with_a_note_not_failed():
 def write_rounds(tmp_path, old, new):
     po, pn = tmp_path / "BENCH_r01.json", tmp_path / "BENCH_r02.json"
     po.write_text(json.dumps({"parsed": old}))  # driver artifact form
-    pn.write_text(json.dumps(new))  # raw bench.py line form
+    pn.write_text(json.dumps(new))  # raw line form (no driver wrapper)
     return str(po), str(pn)
 
 

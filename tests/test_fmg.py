@@ -348,6 +348,30 @@ def test_select_never_beats_default_on_noise():
     assert chosen.engine == chosen.static_engine
 
 
+@pytest.mark.parametrize("tuned_s,demoted", [(2.0, True), (0.5, False)],
+                         ids=["loses", "wins"])
+def test_tune_demotes_a_measured_loser(tmp_path, monkeypatch, tuned_s,
+                                       demoted):
+    """The measured half of never-loses: with ``measure=True`` a winner
+    that clocks slower than the static default is persisted as the
+    static default; one that clocks faster keeps its engine, and the
+    persisted record never attests a tuned time above the default's."""
+    problem = Problem(M=400, N=600)
+    tel = _fake_telemetry(predicted_iters=5000)
+    picked, _rows = autotune.select(problem, tel)
+    assert picked.engine != picked.static_engine
+    clock = {picked.engine: tuned_s, picked.static_engine: 1.0}
+    monkeypatch.setattr(autotune, "_measure_once",
+                        lambda problem, engine, dtype, **kw: clock[engine])
+    reg = autotune.TuneRegistry(os.path.join(tmp_path, "autotune.json"))
+    rep = autotune.tune(problem, registry=reg, persist=True, measure=True,
+                        telemetry=tel)
+    assert rep["demoted_to_static"] is demoted
+    got = autotune.TuneRegistry(reg.path).load().get(rep["key"])
+    assert got.engine == (picked.static_engine if demoted else picked.engine)
+    assert got.measured_t_s <= got.static_measured_t_s == 1.0
+
+
 def test_select_prefers_fmg_at_iteration_walls():
     """Many predicted iterations → the F-cycle's constant work wins on
     the model (the 8192²/28.7 s story in miniature)."""

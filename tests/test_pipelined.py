@@ -230,20 +230,6 @@ def test_sharded_pipelined_through_dispatch_and_cli():
         )
 
 
-def test_multichip_scaling_table_runs_pipelined():
-    from poisson_ellipse_tpu.harness.bench_multichip import scaling_table
-
-    t = scaling_table(
-        "strong", (20, 20), [(1, 1), (2, 2)], dtype="f64",
-        stencil_impl="pipelined",
-    )
-    assert t["stencil_impl"] == "pipelined"
-    assert all(r["converged"] for r in t["rows"])
-    assert all(
-        abs(r["iters"] - WEIGHTED_ORACLE[(20, 20)]) <= 2 for r in t["rows"]
-    )
-
-
 # ------------------------------------------------ structural (static cost)
 
 

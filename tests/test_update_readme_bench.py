@@ -424,7 +424,7 @@ def test_device_falls_back_to_measured_part(workspace):
     tmp, readme, artifact = workspace
     rec = make_artifact()
     del rec["device"]
-    artifact.write_text(json.dumps(rec))  # raw bench.py line form
+    artifact.write_text(json.dumps(rec))  # raw line form (no driver wrapper)
     urb.regenerate(str(readme), str(artifact), root=str(tmp))
     assert urb.MEASURED_DEVICE in readme.read_text()
 

@@ -1,10 +1,10 @@
 """Threshold-based regression gate between two BENCH_r*.json rounds.
 
-Every perf PR gets one number story: the round driver archives
-`bench.py`'s JSON line as `BENCH_r{N}.json`, and this tool diffs any two
-rounds metric by metric against named tolerances, exiting nonzero with
-the offending metric spelled out — a perf regression becomes a failing
-check, not an archaeology project:
+The archived pre-chip rounds (`BENCH_r{N}.json`, one JSON line each,
+from a bench script since retired for `benchmark/`) are diffed here,
+any two rounds metric by metric against named tolerances, exiting
+nonzero with the offending metric spelled out — a perf regression
+becomes a failing check, not an archaeology project:
 
     python tools/bench_compare.py OLD.json NEW.json
     python tools/bench_compare.py              # newest two rounds
@@ -28,8 +28,8 @@ older artifacts predate newer keys, which must never fail the gate):
   more than `precond-t-pct` slower
 - the `abft` row: checks-on overhead creeping more than `abft-pp`
   percentage points between rounds, or the collective-cadence pin
-  (`collectives_identical`) breaking — bench.py's own ≤2% gate bounds
-  the absolute; this catches the trend
+  (`collectives_identical`) breaking — the producing round bounded
+  the absolute at ≤2%; this catches the trend
 - `fleet` rows (keyed by replica count): aggregate `solves_per_sec`
   through the replicated fleet dropping more than `fleet-agg-pct`, the
   `non_decreasing` scaling pin breaking in the new round, and the
@@ -385,8 +385,8 @@ def compare(old: dict, new: dict, tol: dict) -> tuple[list[Regression], list[str
     if bool(old.get("throughput")) != bool(new.get("throughput")):
         notes.append("throughput: only in one round, skipped")
 
-    # the ABFT overhead row: bench.py's own ≤2% gate bounds the absolute
-    # per round; this catches creep between rounds (percentage POINTS —
+    # the ABFT overhead row: the producing round bounded the absolute at
+    # ≤2%; this catches creep between rounds (percentage POINTS —
     # the quantity is already a percent) and the cadence pin breaking
     def live_abft(rec):
         row = rec.get("abft")

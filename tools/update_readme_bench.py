@@ -1,23 +1,23 @@
-"""Regenerate README.md's measured-performance blocks from a BENCH artifact.
+"""Render measured-performance marker blocks from an archived BENCH round.
 
-The README's headline speedup and measured table are GENERATED — not
-hand-edited — from the machine-readable JSON line `bench.py` prints
-(which the round driver archives as `BENCH_r{N}.json`). One number, one
-source:
+The archived pre-chip rounds (`BENCH_r{N}.json`) each hold one
+machine-readable JSON line; this tool renders a headline and a measured
+table from one of them into the ``<!-- bench:... -->`` marker pairs of a
+README. The repository's own README carries no such blocks: its speeds
+come from `BENCHMARK.json` through `PERF.md` and `PERF_LEDGER.jsonl`.
 
-    python bench.py > /tmp/bench.json   # or use the driver's BENCH_r*.json
-    python tools/update_readme_bench.py [/tmp/bench.json]
+    python tools/update_readme_bench.py [BENCH_r05.json]
 
 With no argument the newest `BENCH_r*.json` in the repo root is used —
 "newest" by parsed round number (mtime breaks ties), not filename sort,
 so r100 beats r99 — and the chosen file is echoed. Both formats are
-accepted: the driver artifact (``{"parsed": {...}}``) and bench.py's raw
+accepted: the driver artifact (``{"parsed": {...}}``) and the raw
 stdout line. Every number in the generated text (headline grid,
 iteration count, reference baseline, chip name) is derived from the
 artifact's own rows; nothing is hardcoded here. The tool rewrites the
 text between the ``<!-- bench:... -->`` marker pairs in README.md and
 leaves everything else untouched; artifacts missing any of the
-machine-readable keys are rejected with a pointer to re-run the bench.
+machine-readable keys are rejected.
 """
 
 from __future__ import annotations
@@ -36,7 +36,7 @@ README = os.path.join(ROOT, "README.md")
 REQUIRED_KEYS = ("value", "vs_baseline", "grids", "config2", "eps_sweep", "f64")
 
 # chip the committed budgets/artifacts were measured on: the honest
-# fallback for artifacts that predate bench.py's "device" field
+# fallback for artifacts that predate the rounds' "device" field
 MEASURED_DEVICE = "TPU v5e"
 
 _ROUND_RE = re.compile(r"BENCH_r(\d+)\.json$")
@@ -78,8 +78,8 @@ def load_artifact(path: str | None, root: str = ROOT) -> tuple[dict, str]:
     if missing:
         raise SystemExit(
             f"{path} predates the machine-readable bench rows "
-            f"(missing: {', '.join(missing)}); re-run "
-            "`python bench.py > out.json` and pass that file"
+            f"(missing: {', '.join(missing)}); re-run the round or pass "
+            "one that carries them"
         )
     return rec, os.path.basename(path)
 
@@ -427,7 +427,7 @@ def grad_lines(rec: dict) -> list[str]:
 
 
 def fleet_lines(rec: dict) -> list[str]:
-    """Markdown for the artifact's ``fleet`` key (emitted by bench.py
+    """Markdown for the artifact's ``fleet`` key (emitted by the rounds
     since the replicated-serving layer landed): aggregate solves/sec
     per replica count plus the kill-drill handoff p99 and (since the
     survivability layer) the kill→rejoin recovery p99. Pre-fleet
@@ -486,7 +486,7 @@ def fleet_lines(rec: dict) -> list[str]:
 
 def geometry_lines(rec: dict) -> list[str]:
     """Prose for the artifact's ``geometry`` key (SDF quadrature
-    assembly, emitted by bench.py since the geom layer landed).
+    assembly, emitted by the rounds since the geom layer landed).
     Pre-geometry artifacts lack the key and render without the lines; a
     failed row (no composite t_solver_s) renders the parity half only —
     absence and partial are both supported inputs, not errors."""
@@ -522,7 +522,7 @@ def geometry_lines(rec: dict) -> list[str]:
 
 
 def precond_lines(rec: dict) -> list[str]:
-    """Markdown for the artifact's ``precond`` key (emitted by bench.py
+    """Markdown for the artifact's ``precond`` key (emitted by the rounds
     since the multigrid layer landed): mg-pcg/cheb-pcg vs diag-PCG per
     grid. Pre-multigrid artifacts lack the key and render without the
     table; a failed row (no iters) is skipped, not a crash."""
@@ -560,7 +560,7 @@ def precond_lines(rec: dict) -> list[str]:
 
 
 def spectrum_lines(rec: dict) -> list[str]:
-    """Markdown for the artifact's ``spectrum`` key (emitted by bench.py
+    """Markdown for the artifact's ``spectrum`` key (emitted by the rounds
     since the diagnostics layer landed): the κ-per-grid table with
     predicted-vs-actual iterations. Pre-diagnostics artifacts lack the
     key and render without the table; a failed row (no kappa — the
@@ -601,7 +601,7 @@ def spectrum_lines(rec: dict) -> list[str]:
 
 def serving_lines(rec: dict) -> list[str]:
     """Markdown for the artifact's serving keys (``throughput`` /
-    ``coldstart``, emitted by bench.py since the batch layer landed).
+    ``coldstart``, emitted by the rounds since the batch layer landed).
     Pre-batch artifacts lack the keys and render without these lines;
     a failed/partial row (no solves_per_sec) is skipped, not a crash."""
     lines: list[str] = []
@@ -652,7 +652,7 @@ def serving_lines(rec: dict) -> list[str]:
 
 def observability_lines(rec: dict) -> list[str]:
     """Prose for the artifact's observability keys (``convergence`` /
-    ``collectives``, emitted by bench.py since the obs layer landed).
+    ``collectives``, emitted by the rounds since the obs layer landed).
     Pre-obs artifacts simply lack the keys and render without these
     lines — absence is a supported input, not an error."""
     lines: list[str] = []
